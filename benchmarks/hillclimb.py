@@ -50,6 +50,7 @@ def main() -> None:
     # run the variant in a fresh subprocess (device-count isolation)
     code = f"""
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 import json
 from repro.launch import dryrun

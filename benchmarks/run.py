@@ -73,6 +73,7 @@ def main() -> None:
                     help="rolling per-PR trajectory JSONL ('' disables)")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    compat.enable_compile_cache()
 
     # capability header: every artifact records native vs. emulated paths
     rep = compat.report()

@@ -1,36 +1,34 @@
-"""Capability detection + compatibility layer (the portability tentpole).
+"""Capability detection + compatibility layer.
 
 The paper's methodology is *portable* characterization: drop the probe
 suite on a device and report what that device actually supports — which
 mma formats are native vs. emulated, which pipeline a dot really lowers
 to, and so on.  This module applies the same philosophy to the software
-stack the reproduction runs on:
+stack the reproduction runs on (Python 3.12, JAX 0.9.0, pinned in
+``pyproject.toml``):
 
-* **JAX version probing** — the repo targets current Pallas/TPU APIs but
-  must degrade gracefully on older/newer installs (``pltpu.CompilerParams``
-  vs ``pltpu.TPUCompilerParams``; ``check_vma`` vs ``check_rep``).
-* **Low-precision dtype registry** — fp8/fp6/fp4 availability differs per
-  JAX version.  Every format resolves to a *container* dtype JAX can hold
-  plus an optional ``ml_dtypes`` host-rounding dtype, so fp4 degrades to
-  fp4-rounded values in an fp8 container instead of an import crash
-  (numerically exact fp4 in a byte-aligned box).  Sub-byte formats
-  additionally carry a :class:`repro.lowbits.PackedSpec` — true
-  bit-packed storage (fp4 2 values/byte, fp6 4 values in 3 bytes, the
-  paper's Tab V tile packing) that ``serve.quant``/``kernels.qmatmul``
-  use for HBM-resident weights and that storage accounting reports as
-  measured bytes/element.
-* **shard_map resolution** — ``jax.shard_map`` (new) vs
-  ``jax.experimental.shard_map.shard_map`` (older), with kwarg
-  translation between ``check_vma`` and ``check_rep``.
-* **pallas_call wrapper** — transparently selects native Mosaic
-  compilation on TPU vs ``interpret=True`` everywhere else, and builds
-  ``compiler_params`` through whichever class this JAX exposes.
+* **Backend probing** — the platform of ``jax.devices()[0]``.  A backend
+  that fails to initialise raises; nothing here turns a missing chip
+  into a CPU run.
+* **Low-precision dtype registry** — every format resolves to a
+  *container* dtype JAX can hold plus an optional ``ml_dtypes``
+  host-rounding dtype: fp8 and fp4 are native jnp dtypes, fp6 rides a
+  host-rounded e4m3 container (numerically exact fp6 in a byte-aligned
+  box).  Sub-byte formats additionally carry a
+  :class:`repro.lowbits.PackedSpec` — true bit-packed storage (fp4 2
+  values/byte, fp6 4 values in 3 bytes, the paper's Tab V tile packing)
+  that ``serve.quant``/``kernels.qmatmul`` use for HBM-resident weights
+  and that storage accounting reports as measured bytes/element.
+* **pallas_call wrapper** — native Mosaic compilation on TPU,
+  ``interpret=True`` only on the CPU platform.
+* **Compile cache** — :func:`enable_compile_cache`, called by the entry
+  points (never on import).
 * **``report()``** — a machine-readable capability report printed at the
   top of every benchmark artifact so each measurement records which paths
   ran native vs. emulated.
 
 Everything here probes *lazily* and caches: importing this module never
-touches a device or raises on a missing feature.
+touches a device.
 """
 
 from __future__ import annotations
@@ -38,8 +36,8 @@ from __future__ import annotations
 import dataclasses
 import functools
 import importlib.util
-import inspect
 import os
+import pathlib
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -62,10 +60,9 @@ __all__ = [
     "packed_spec",
     "storage_bytes_per_element",
     "shard_map",
-    "resolve_shard_map",
     "pallas_interpret_default",
-    "tpu_compiler_params",
     "pallas_call",
+    "enable_compile_cache",
     "vmem_budget_bytes",
     "has_hypothesis",
     "CompatReport",
@@ -91,11 +88,9 @@ def jax_version() -> Tuple[int, ...]:
 
 @functools.lru_cache(maxsize=None)
 def backend_platform() -> str:
-    """Default-backend platform string: 'tpu' | 'gpu' | 'cpu'."""
-    try:
-        return jax.devices()[0].platform
-    except Exception:
-        return "cpu"
+    """Default-backend platform string: 'tpu' | 'gpu' | 'cpu'.  A
+    backend that fails to initialise raises out of here."""
+    return jax.devices()[0].platform
 
 
 def is_tpu() -> bool:
@@ -224,91 +219,20 @@ def storage_bytes_per_element(name: str, packed: bool = True) -> float:
     return float(np.dtype(spec.container).itemsize)
 
 
-# --------------------------------------------------------------------- #
-# shard_map resolution
-# --------------------------------------------------------------------- #
-
-@functools.lru_cache(maxsize=None)
-def resolve_shard_map() -> Tuple[Callable, str]:
-    """(shard_map callable, where it came from)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn, "jax.shard_map"
-    from jax.experimental.shard_map import shard_map as fn  # noqa: F811
-    return fn, "jax.experimental.shard_map"
-
-
-@functools.lru_cache(maxsize=None)
-def _shard_map_params() -> frozenset:
-    fn, _ = resolve_shard_map()
-    try:
-        return frozenset(inspect.signature(fn).parameters)
-    except (TypeError, ValueError):
-        return frozenset()
-
-
-def shard_map(f: Optional[Callable] = None, **kwargs):
-    """Version-portable ``shard_map``.
-
-    Accepts either kwarg spelling of the replication check
-    (``check_vma`` — new JAX — or ``check_rep`` — old) and translates to
-    whatever the installed ``shard_map`` understands; unsupported kwargs
-    are dropped rather than raised.  Usable directly or as a decorator
-    factory (``shard_map(mesh=..., ...)(f)``), mirroring upstream.
-    """
-    if f is None:
-        return functools.partial(shard_map, **kwargs)
-    fn, _ = resolve_shard_map()
-    params = _shard_map_params()
-    check = kwargs.pop("check_vma", kwargs.pop("check_rep", None))
-    if check is not None:
-        if "check_vma" in params:
-            kwargs["check_vma"] = check
-        elif "check_rep" in params:
-            kwargs["check_rep"] = check
-    if params:
-        kwargs = {k: v for k, v in kwargs.items() if k in params}
-    return fn(f, **kwargs)
+# ``jax.shard_map`` (its replication check is spelled ``check_vma``)
+shard_map = jax.shard_map
 
 
 # --------------------------------------------------------------------- #
-# Pallas: interpret-mode fallback + compiler-params portability
+# Pallas: interpret mode on the CPU only
 # --------------------------------------------------------------------- #
 
 def pallas_interpret_default() -> bool:
-    """True off-TPU: run kernels through the Pallas interpreter so the
-    whole suite executes (and is testable) on any backend; Mosaic-compile
-    natively when real hardware is present."""
-    return not is_tpu()
-
-
-@functools.lru_cache(maxsize=None)
-def _compiler_params_cls() -> Tuple[Optional[type], str]:
-    from jax.experimental.pallas import tpu as pltpu
-
-    for attr in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, attr, None)
-        if cls is not None:
-            return cls, f"pltpu.{attr}"
-    return None, "dict"
-
-
-def tpu_compiler_params(**kwargs):
-    """Build TPU compiler params via whichever API this JAX exposes.
-
-    ``pltpu.CompilerParams`` (new) -> ``pltpu.TPUCompilerParams`` (older)
-    -> plain ``dict(mosaic=...)`` (oldest).  Kwargs the installed class
-    doesn't know are dropped so callers can always pass the full set.
-    """
-    cls, _ = _compiler_params_cls()
-    if cls is None:
-        return dict(mosaic=dict(kwargs))
-    try:
-        fields = {f.name for f in dataclasses.fields(cls)}
-        kwargs = {k: v for k, v in kwargs.items() if k in fields}
-    except TypeError:
-        pass
-    return cls(**kwargs)
+    """True on the CPU platform: run kernels through the Pallas
+    interpreter so the suite executes there; Mosaic-compile natively
+    on every other platform (a kernel that cannot compile then fails
+    instead of silently running interpreted)."""
+    return backend_platform() == "cpu"
 
 
 def vmem_budget_bytes() -> int:
@@ -335,21 +259,43 @@ def pallas_call(kernel: Callable, *, interpret: Optional[bool] = None,
     """``pl.pallas_call`` with capability-aware defaults.
 
     * ``interpret=None`` resolves via :func:`pallas_interpret_default` —
-      native Mosaic on TPU, interpreter elsewhere.
-    * ``dimension_semantics`` builds ``compiler_params`` through
-      :func:`tpu_compiler_params`, insulating kernels from the
-      CompilerParams/TPUCompilerParams rename.
+      the interpreter on the CPU, native Mosaic elsewhere.
+    * ``dimension_semantics`` builds ``pltpu.CompilerParams``.
     """
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     if interpret is None:
         interpret = pallas_interpret_default()
     if compiler_params is None and dimension_semantics is not None:
-        compiler_params = tpu_compiler_params(
+        compiler_params = pltpu.CompilerParams(
             dimension_semantics=tuple(dimension_semantics))
     if compiler_params is not None:
         kwargs["compiler_params"] = compiler_params
     return pl.pallas_call(kernel, interpret=interpret, **kwargs)
+
+
+# --------------------------------------------------------------------- #
+# Persistent compile cache
+# --------------------------------------------------------------------- #
+
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache; returns its directory.
+
+    Entry points call this first thing (importing ``repro`` never
+    does).  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+    itself and nothing is changed here.  Otherwise the cache goes to
+    ``<checkout>/.jax_cache``: a fixed path, since the path is part of
+    what a later process must find again."""
+    env = os.environ.get(CACHE_ENV)
+    if env:
+        return env
+    path = str(pathlib.Path(__file__).resolve().parents[2] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # --------------------------------------------------------------------- #
@@ -371,8 +317,6 @@ class CompatReport:
     platform: str
     device_count: int
     pallas_mode: str             # "native-mosaic" | "interpret"
-    compiler_params_api: str
-    shard_map_source: str
     formats: Dict[str, str]      # name -> "native" | "emulated (...)"
     hypothesis: bool
 
@@ -381,8 +325,6 @@ class CompatReport:
             f"compat,jax={self.jax_version},platform={self.platform},"
             f"devices={self.device_count}",
             f"compat,pallas={self.pallas_mode},"
-            f"compiler_params={self.compiler_params_api},"
-            f"shard_map={self.shard_map_source},"
             f"hypothesis={'yes' if self.hypothesis else 'no'}",
         ]
         out += [f"compat,format={name},{how}"
@@ -397,20 +339,12 @@ def report() -> CompatReport:
     """Probe everything once and return the capability report that the
     benchmark runner and examples print at startup, so every artifact
     records which paths ran native vs. emulated."""
-    _, cp_api = _compiler_params_cls()
-    _, sm_src = resolve_shard_map()
-    try:
-        n_dev = jax.device_count()
-    except Exception:
-        n_dev = 0
     return CompatReport(
         jax_version=jax.__version__,
         platform=backend_platform(),
-        device_count=n_dev,
+        device_count=jax.device_count(),
         pallas_mode="interpret" if pallas_interpret_default()
         else "native-mosaic",
-        compiler_params_api=cp_api,
-        shard_map_source=sm_src,
         formats={name: spec.describe()
                  for name, spec in dtype_registry().items()},
         hypothesis=has_hypothesis(),

@@ -92,9 +92,9 @@ class DeviceModel:
 # Published target models
 # ---------------------------------------------------------------------------
 
-# TPU v5e — the production target for this framework.
-#   197 TFLOP/s bf16 / 394 TOP/s int8, 16 GiB HBM2 @ 819 GB/s,
-#   ~128 MiB VMEM per core (software-managed), 4 ICI links ~50 GB/s each.
+# TPU v5e — the production target for this framework.  Peaks from Google
+# Cloud's "TPU v5e" documentation: 197 TFLOP/s bf16, 393 TOP/s int8,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect.
 TPU_V5E = DeviceModel(
     name="tpu-v5e",
     vendor="google",
@@ -103,7 +103,7 @@ TPU_V5E = DeviceModel(
     peak_flops={
         "bfloat16": 197e12,
         "float32": 98.5e12,        # fp32 via MXU passthrough at half rate
-        "int8": 394e12,
+        "int8": 393e12,
         # fp8/fp6/fp4 are NOT native on v5e: emulated via bf16 MXU after
         # dequant (see DESIGN.md §3) — peak_flops_for() falls back to bf16.
     },
@@ -122,6 +122,8 @@ TPU_V5E = DeviceModel(
     peak_watts=220.0,
 )
 
+# GH100 and GB203 are the paper's two GPUs, kept as named models for
+# side-by-side tables; detect_backend_model never picks them.
 # GH100 (H100 PCIe) — the paper's Hopper column (Tab I/II + §VI measurements).
 GH100 = DeviceModel(
     name="gh100-h100-pcie",
@@ -222,13 +224,26 @@ def get_device_model(name: str) -> DeviceModel:
         ) from None
 
 
+# ``device_kind`` as JAX reports it -> published model.  A device that is
+# not listed is an error, never a default.
+BY_DEVICE_KIND: Dict[str, DeviceModel] = {
+    "TPU v5 lite": TPU_V5E,        # what a v5e chip reports
+}
+
+
 def detect_backend_model() -> DeviceModel:
-    """Best-effort model for the backend JAX is actually running on."""
+    """The model of the device JAX is running on, looked up by
+    ``device_kind``; the CPU platform maps to :data:`HOST_CPU`.  Raises
+    for any other device."""
     import jax
 
-    platform = jax.devices()[0].platform
-    if platform == "tpu":
-        return TPU_V5E
-    if platform == "gpu":
-        return GH100
-    return HOST_CPU
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return HOST_CPU
+    try:
+        return BY_DEVICE_KIND[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no device model for {dev.platform} device_kind "
+            f"{dev.device_kind!r}; known: {sorted(BY_DEVICE_KIND)}"
+        ) from None

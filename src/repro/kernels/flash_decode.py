@@ -39,7 +39,9 @@ def _attend_block(q, k, v, slot_pos, pos, o_ref, m_scr, l_scr, acc_scr, *,
                   window: Optional[int], softcap: Optional[float],
                   scale: float):
     """Shared online-softmax body: one (1, d) query against one (bk, d)
-    KV block, scratch-carried m/l/acc, finalize on the last block."""
+    KV block, scratch-carried m/l/acc, finalize on the last block.
+    ``slot_pos`` is the block's (1, bk) row and ``pos`` a scalar; every
+    value stays 2-D so Mosaic lowers it."""
     j = pl.program_id(1)
     nj = pl.num_programs(1)
 
@@ -56,18 +58,17 @@ def _attend_block(q, k, v, slot_pos, pos, o_ref, m_scr, l_scr, acc_scr, *,
     ok = (slot_pos >= 0) & (slot_pos <= pos)
     if window is not None:
         ok &= slot_pos > pos - window
-    s = jnp.where(ok[None, :], s, NEG_INF)            # (1, bk)
+    s = jnp.where(ok, s, NEG_INF)                      # (1, bk)
 
-    m_prev = m_scr[...][:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    m_prev = m_scr[...]                                # (1, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_scr[...] = l_scr[...] * corr[:, None] + jnp.sum(p, axis=1,
-                                                      keepdims=True)
-    acc_scr[...] = (acc_scr[...] * corr[:, None]
+    p = jnp.exp(s - m_new)
+    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = (acc_scr[...] * corr
                     + jax.lax.dot(p, v,
                                   preferred_element_type=jnp.float32))
-    m_scr[...] = m_new[:, None]
+    m_scr[...] = m_new
 
     @pl.when(j == nj - 1)
     def _finalize():
@@ -78,13 +79,13 @@ def _attend_block(q, k, v, slot_pos, pos, o_ref, m_scr, l_scr, acc_scr, *,
 
 def _kernel(pos_ref, q_ref, k_ref, v_ref, sp_ref, o_ref,
             m_scr, l_scr, acc_scr, *,
-            bk: int, window: Optional[int], softcap: Optional[float],
-            scale: float):
+            bk: int, hq: int, window: Optional[int],
+            softcap: Optional[float], scale: float):
     q = q_ref[0].astype(jnp.float32)                  # (1, d)
     k = k_ref[0].astype(jnp.float32)                  # (bk, d)
     v = v_ref[0].astype(jnp.float32)                  # (bk, d)
-    _attend_block(q, k, v, sp_ref[0], pos_ref[0], o_ref,
-                  m_scr, l_scr, acc_scr,
+    pos = pos_ref[pl.program_id(0) // hq]             # SMEM scalar
+    _attend_block(q, k, v, sp_ref[0], pos, o_ref, m_scr, l_scr, acc_scr,
                   window=window, softcap=softcap, scale=scale)
 
 
@@ -94,26 +95,25 @@ def _expand_kv_tile(stored, s_codes, *, fmt: str, packed: bool, d: int,
     (bk, d) fp32, in VMEM — dequant-on-the-way-in (shift/mask/exp2 only,
     no ml_dtypes: the ``repro.lowbits`` in-kernel codec)."""
     if packed:
-        vals = lowbits.decode(lowbits.unpack_codes(stored, fmt), fmt)
+        vals = lowbits.unpack_tile(stored, fmt)
     else:
         vals = stored.astype(jnp.float32)
     scales = lowbits.e8m0_decode(s_codes)             # (bk, d/blk)
-    bkk = vals.shape[0]
-    return (vals.reshape(bkk, d // blk, blk)
-            * scales[:, :, None]).reshape(bkk, d)
+    return vals * lowbits.spread_scales(scales, blk, d)
 
 
 def _quant_kernel(pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, sp_ref,
                   o_ref, m_scr, l_scr, acc_scr, *,
-                  bk: int, window: Optional[int], softcap: Optional[float],
-                  scale: float, fmt: str, packed: bool, d: int, blk: int):
+                  bk: int, hq: int, window: Optional[int],
+                  softcap: Optional[float], scale: float, fmt: str,
+                  packed: bool, d: int, blk: int):
     q = q_ref[0].astype(jnp.float32)                  # (1, d)
     k = _expand_kv_tile(kq_ref[0], ks_ref[0], fmt=fmt, packed=packed,
                         d=d, blk=blk)                 # (bk, d)
     v = _expand_kv_tile(vq_ref[0], vs_ref[0], fmt=fmt, packed=packed,
                         d=d, blk=blk)                 # (bk, d)
-    _attend_block(q, k, v, sp_ref[0], pos_ref[0], o_ref,
-                  m_scr, l_scr, acc_scr,
+    pos = pos_ref[pl.program_id(0) // hq]             # SMEM scalar
+    _attend_block(q, k, v, sp_ref[0], pos, o_ref, m_scr, l_scr, acc_scr,
                   window=window, softcap=softcap, scale=scale)
 
 
@@ -145,17 +145,18 @@ def flash_decode_bhd(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     def kv_index(g, j):
         return (g // hq) * hkv + (g % hq) // ratio, j, 0
 
-    kernel = functools.partial(_kernel, bk=bk, window=window,
+    kernel = functools.partial(_kernel, bk=bk, hq=hq, window=window,
                                softcap=softcap, scale=scale)
     out = compat.pallas_call(
         kernel,
         grid=(b * hq, S_pad // bk),
         in_specs=[
-            pl.BlockSpec((1,), lambda g, j: (g // hq,)),        # pos
+            pl.BlockSpec(memory_space=pltpu.SMEM),              # pos
             pl.BlockSpec((1, 1, d), lambda g, j: (g, 0, 0)),    # q
             pl.BlockSpec((1, bk, d), kv_index),                 # k
             pl.BlockSpec((1, bk, d), kv_index),                 # v
-            pl.BlockSpec((1, bk), lambda g, j: (g // hq, j)),   # slot_pos
+            # slot_pos viewed (b, 1, S): (1, bk) tiles meet Mosaic's rule
+            pl.BlockSpec((1, 1, bk), lambda g, j: (g // hq, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, 1, d), lambda g, j: (g, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * hq, 1, d), q.dtype),
@@ -166,7 +167,7 @@ def flash_decode_bhd(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         ],
         dimension_semantics=("parallel", "arbitrary"),
         interpret=interpret,
-    )(pos.astype(jnp.int32), qf, kf, vf, slot_pos)
+    )(pos.astype(jnp.int32), qf, kf, vf, slot_pos[:, None, :])
     return out.reshape(b, hq, d)
 
 
@@ -233,19 +234,20 @@ def flash_decode_quant_bhd(q: jax.Array,
         return (g // hq) * hkv + (g % hq) // ratio, j, 0
 
     kernel = functools.partial(
-        _quant_kernel, bk=bk, window=window, softcap=softcap, scale=scale,
-        fmt=fmt, packed=packed, d=d, blk=blk)
+        _quant_kernel, bk=bk, hq=hq, window=window, softcap=softcap,
+        scale=scale, fmt=fmt, packed=packed, d=d, blk=blk)
     out = compat.pallas_call(
         kernel,
         grid=(b * hq, S_pad // bk),
         in_specs=[
-            pl.BlockSpec((1,), lambda g, j: (g // hq,)),          # pos
+            pl.BlockSpec(memory_space=pltpu.SMEM),                # pos
             pl.BlockSpec((1, 1, d), lambda g, j: (g, 0, 0)),      # q
             pl.BlockSpec((1, bk, stored_d), kv_index),            # k codes
             pl.BlockSpec((1, bk, n_blk), kv_index),               # k scales
             pl.BlockSpec((1, bk, stored_d), kv_index),            # v codes
             pl.BlockSpec((1, bk, n_blk), kv_index),               # v scales
-            pl.BlockSpec((1, bk), lambda g, j: (g // hq, j)),     # slot_pos
+            # slot_pos viewed (b, 1, S): (1, bk) tiles meet Mosaic's rule
+            pl.BlockSpec((1, 1, bk), lambda g, j: (g // hq, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, 1, d), lambda g, j: (g, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * hq, 1, d), q.dtype),
@@ -256,5 +258,5 @@ def flash_decode_quant_bhd(q: jax.Array,
         ],
         dimension_semantics=("parallel", "arbitrary"),
         interpret=interpret,
-    )(pos.astype(jnp.int32), qf, kqf, ksf, vqf, vsf, slot_pos)
+    )(pos.astype(jnp.int32), qf, kqf, ksf, vqf, vsf, slot_pos[:, None, :])
     return out.reshape(b, hq, d)

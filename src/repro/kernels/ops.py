@@ -130,7 +130,7 @@ def ssd_scan(x: jax.Array, dt_a: jax.Array, b: jax.Array, c: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk"))
 def qmatmul(x: jax.Array, qw: jax.Array, scales: jax.Array, *,
-            bm: int = 128, bn: int = 128, bk: int = 128) -> jax.Array:
+            bm: int = 128, bn: int = 128, bk: int = 256) -> jax.Array:
     """x (m, k) @ dequant(qw (n, k)).T with e8m0 block scales (n, k/32)."""
     m, k = x.shape
     pad_m = (-m) % bm
@@ -144,7 +144,7 @@ def qmatmul(x: jax.Array, qw: jax.Array, scales: jax.Array, *,
 @functools.partial(jax.jit, static_argnames=("fmt", "bm", "bn", "bk"))
 def qmatmul_packed(x: jax.Array, pw: jax.Array, scales: jax.Array,
                    fmt: str, *,
-                   bm: int = 128, bn: int = 128, bk: int = 128
+                   bm: int = 128, bn: int = 128, bk: int = 256
                    ) -> jax.Array:
     """x (m, k) @ dequant(unpack(pw), scales).T with bit-packed weights.
 

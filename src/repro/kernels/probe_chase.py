@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import compat
 
@@ -41,7 +42,7 @@ def chase(buf: jax.Array, steps: int,
     return compat.pallas_call(
         kernel,
         in_specs=[pl.BlockSpec(buf.shape, lambda: (0, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda: (0, 0)),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),  # scalar result
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
         interpret=interpret,
     )(buf)[0, 0]

@@ -48,10 +48,10 @@ def _accumulate(x_ref, s_ref, o_ref, acc, w, *, bk: int):
         acc[...] = jnp.zeros_like(acc)
 
     x = x_ref[...].astype(jnp.float32)                 # (bm, bk)
-    sc = s_ref[...]                                    # (bn, bk/32)
-    bn = w.shape[0]
-    w = (w.reshape(bn, bk // BLOCK, BLOCK) * sc[..., None]
-         ).reshape(bn, bk)
+    # s_ref holds the whole (bn, k/32) scale row (a (bn, bk/32) block
+    # breaks Mosaic's (8, 128) tiling rule); pick this k step's columns
+    w = w * lowbits.spread_scales(s_ref[...], BLOCK, bk,
+                                  first=ki * (bk // BLOCK))
     acc[...] += jax.lax.dot_general(
         x, w, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -68,13 +68,12 @@ def _kernel(x_ref, qw_ref, s_ref, o_ref, acc, *, bk: int):
 
 def _packed_kernel(x_ref, pw_ref, s_ref, o_ref, acc, *, bk: int, fmt: str):
     # (bn, bk*b/8) uint8 -> expand to (bn, bk) fp32 in VMEM
-    codes = lowbits.unpack_codes(pw_ref[...], fmt)
-    w = lowbits.decode(codes, fmt)
+    w = lowbits.unpack_tile(pw_ref[...], fmt)
     _accumulate(x_ref, s_ref, o_ref, acc, w, bk=bk)
 
 
 def qmatmul_mkn(x: jax.Array, qw: jax.Array, scales: jax.Array, *,
-                bm: int = 128, bn: int = 128, bk: int = 128,
+                bm: int = 128, bn: int = 128, bk: int = 256,
                 out_dtype=jnp.bfloat16,
                 interpret: Optional[bool] = None) -> jax.Array:
     """x (m, k) @ dequant(qw (n, k), scales (n, k/32)).T -> (m, n).
@@ -83,6 +82,7 @@ def qmatmul_mkn(x: jax.Array, qw: jax.Array, scales: jax.Array, *,
     interpreter elsewhere (``repro.compat``)."""
     m, k = x.shape
     n = qw.shape[0]
+    bk = min(bk, k)
     assert qw.shape == (n, k) and scales.shape == (n, k // BLOCK)
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, (m, n, k)
     assert bk % BLOCK == 0
@@ -93,7 +93,7 @@ def qmatmul_mkn(x: jax.Array, qw: jax.Array, scales: jax.Array, *,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bn, bk), lambda i, j, kk: (j, kk)),
-            pl.BlockSpec((bn, bk // BLOCK), lambda i, j, kk: (j, kk)),
+            pl.BlockSpec((bn, k // BLOCK), lambda i, j, kk: (j, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
@@ -105,7 +105,7 @@ def qmatmul_mkn(x: jax.Array, qw: jax.Array, scales: jax.Array, *,
 
 def qmatmul_packed_mkn(x: jax.Array, pw: jax.Array, scales: jax.Array,
                        fmt: str, *,
-                       bm: int = 128, bn: int = 128, bk: int = 128,
+                       bm: int = 128, bn: int = 128, bk: int = 256,
                        out_dtype=jnp.bfloat16,
                        interpret: Optional[bool] = None) -> jax.Array:
     """Like :func:`qmatmul_mkn` but with bit-packed weight storage.
@@ -119,6 +119,7 @@ def qmatmul_packed_mkn(x: jax.Array, pw: jax.Array, scales: jax.Array,
     spec = lowbits.packed_spec(fmt)
     m, k = x.shape
     n = pw.shape[0]
+    bk = min(bk, k)
     g, bpg = spec.values_per_group, spec.bytes_per_group
     assert k % g == 0 and bk % g == 0, (k, bk, fmt)
     kb, bkb = k * bpg // g, bk * bpg // g      # packed bytes: total, block
@@ -133,7 +134,7 @@ def qmatmul_packed_mkn(x: jax.Array, pw: jax.Array, scales: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bn, bkb), lambda i, j, kk: (j, kk)),
-            pl.BlockSpec((bn, bk // BLOCK), lambda i, j, kk: (j, kk)),
+            pl.BlockSpec((bn, k // BLOCK), lambda i, j, kk: (j, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),

@@ -1,11 +1,14 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST run before any jax-importing import — jax locks
-the device count at first init.  (They are intentionally before the
-module docstring's imports, per the deployment spec.)
+The lines above MUST run before any jax-importing import — jax locks
+the platform and device count at first init.  (They are intentionally
+before the module docstring's imports, per the deployment spec.)  The
+dry-run simulates the pod on host devices, so it pins the CPU platform
+and never takes a chip from another process.
 
 For each cell this:
   1. builds parameter / optimizer / batch / cache ShapeDtypeStructs

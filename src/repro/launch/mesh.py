@@ -21,7 +21,7 @@ from typing import Tuple
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -56,8 +56,10 @@ def make_serving_mesh(shape) -> "Mesh | None":
     1-tuple (pure tensor parallel: axis ('model',)), or a 2-tuple
     (('data', 'model') — slots over 'data', heads/vocab over 'model').
     Also accepts a "2x2"-style string (the CLI/benchmark ``--mesh``
-    flag).  Uses the first prod(shape) devices, so it composes with
-    ``--xla_force_host_platform_device_count``."""
+    flag).  ``jax.make_mesh`` lays the first prod(shape) devices out on
+    the chips' physical topology, so it composes with
+    ``--xla_force_host_platform_device_count``.  Axes are ``Auto``: the
+    engine places every array itself."""
     if shape is None:
         return None
     if isinstance(shape, str):
@@ -77,4 +79,6 @@ def make_serving_mesh(shape) -> "Mesh | None":
             f"--xla_force_host_platform_device_count={n} (before jax "
             f"initializes) or shrink the mesh")
     axes = ("model",) if len(shape) == 1 else ("data", "model")
-    return Mesh(np.array(devices[:n]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices[:n])

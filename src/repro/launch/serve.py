@@ -27,7 +27,7 @@ import os
 import time
 
 
-def main() -> None:
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gptneox-1b")
     ap.add_argument("--reduced", action="store_true")
@@ -44,12 +44,17 @@ def main() -> None:
     ap.add_argument("--precision", default="bfloat16",
                     help="float32|bfloat16|float8_e4m3fn|float8_e5m2|"
                          "float6_e2m3fn|float6_e3m2fn|float4_e2m1fn")
+    ap.add_argument("--kv-format", default=None,
+                    help="quantized KV storage, e.g. float8_e4m3fn or "
+                         "float4_e2m1fn; omit for compute-dtype KV")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="parameter init seed")
     ap.add_argument("--mesh", default=None,
                     help="serving mesh shape, e.g. 2x2 (data x model) "
                          "or 4 (pure TP); omit for single-device")
     ap.add_argument("--fake-devices", type=int, default=0,
                     help="XLA host-platform fake device count (CPU mesh "
-                         "smoke runs); set before jax backend init")
+                         "smoke runs); pins the CPU platform")
     ap.add_argument("--scenario", default=None,
                     choices=["poisson", "bursty", "ramp"],
                     help="replay a seeded arrival trace instead of "
@@ -66,30 +71,30 @@ def main() -> None:
     ap.add_argument("--deadline-ms", type=float, default=None,
                     help="per-request deadline from submit; expired "
                          "requests finish as deadline_exceeded")
-    args = ap.parse_args()
+    return ap
 
-    if args.fake_devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.fake_devices} "
-            + os.environ.get("XLA_FLAGS", ""))
 
+def build_engine(args: argparse.Namespace):
+    """The engine the CLI serves with, built from parsed arguments:
+    random weights from ``--seed``, quantized to ``--precision``, on the
+    ``--mesh`` (one device when omitted).  ``chip_smoke.py`` builds its
+    engines through here too."""
     import jax
 
     from repro.configs import get_config
     from repro.launch.mesh import make_serving_mesh
     from repro.models import build_model
-    from repro.serve import (AdmissionConfig, ServeEngine,
-                             quantize_params, replay)
-    from repro.serve.traffic import TRACES
+    from repro.serve import AdmissionConfig, ServeEngine, quantize_params
 
     mesh = make_serving_mesh(args.mesh)
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
     params, qstats = quantize_params(params, args.precision)
     print(f"[serve] {cfg.name} precision={args.precision} "
+          f"kv_format={args.kv_format} "
           f"quantized_bytes={qstats['quantized_bytes']/2**20:.1f} MiB "
           f"rel-mse={qstats['mse']:.2e}"
           + (f" mesh={dict(mesh.shape)}" if mesh is not None else ""))
@@ -100,12 +105,33 @@ def main() -> None:
         admission = AdmissionConfig(
             queue_limit=args.queue_limit, policy=args.policy,
             scheduler=args.scheduler, deadline_ms=args.deadline_ms)
-    engine = ServeEngine(model, params, batch=args.batch,
-                         max_seq=args.max_seq,
-                         temperature=args.temperature,
-                         decode_block=args.decode_block,
-                         prefill_chunk=args.prefill_chunk,
-                         mesh=mesh, admission=admission)
+    return ServeEngine(model, params, batch=args.batch,
+                       max_seq=args.max_seq,
+                       temperature=args.temperature,
+                       kv_format=args.kv_format,
+                       decode_block=args.decode_block,
+                       prefill_chunk=args.prefill_chunk,
+                       mesh=mesh, admission=admission)
+
+
+def main() -> None:
+    args = make_parser().parse_args()
+    if args.fake_devices:
+        # a simulated mesh lives on host devices: never claim a chip
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["XLA_FLAGS"] = (
+            f"--xla_force_host_platform_device_count={args.fake_devices} "
+            + os.environ.get("XLA_FLAGS", ""))
+
+    import jax
+
+    from repro import compat
+    from repro.serve import replay
+    from repro.serve.traffic import TRACES
+
+    compat.enable_compile_cache()
+    engine = build_engine(args)
+    cfg = engine.model.cfg
 
     if args.scenario:
         trace_args = {

@@ -19,6 +19,8 @@ import os
 import jax
 from jax.sharding import PartitionSpec as P
 
+from repro import compat
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -36,6 +38,7 @@ def main() -> None:
     ap.add_argument("--production-mesh", action="store_true")
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
+    compat.enable_compile_cache()
 
     if "TPU_PROCESS_BOUNDS" in os.environ:      # multi-host pod
         jax.distributed.initialize()

@@ -322,6 +322,63 @@ def unpack(packed, fmt: str, n: int):
 
 
 # --------------------------------------------------------------------- #
+# 2-D tile helpers for Pallas kernel bodies
+# --------------------------------------------------------------------- #
+# Mosaic cannot lower a reshape that splits or interleaves the lane
+# (last) axis, which is what unpack_codes' stack + reshape and a
+# per-block scale broadcast through (rows, n, blk) do.  These helpers
+# move values across lanes with 0/1 matmuls instead.  Each output
+# element is one input value times 1 plus zeros, so the result is
+# exact, on the interpreter and on the MXU alike (fp4 values and
+# power-of-two scales are exact in bf16).
+
+def _onehot(n: int, width: int, hit):
+    """(n, width) float32, 1 where ``hit(row, col)`` holds, else 0."""
+    import jax
+    import jax.numpy as jnp
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, width), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, width), 1)
+    return hit(row, col).astype(jnp.float32)
+
+
+def unpack_tile(packed, fmt: str):
+    """(rows, nbytes) uint8 tile -> (rows, values) float32, in-kernel.
+
+    fp4 decodes the low and high nibble planes separately and
+    interleaves them with one-hot matmuls, so it lowers in Mosaic.
+    fp6 goes through :func:`unpack_codes` and runs only under the
+    Pallas interpreter."""
+    if fmt != "float4_e2m1fn":
+        return decode(unpack_codes(packed, fmt), fmt)
+    import jax
+    import jax.numpy as jnp
+
+    b = packed.astype(jnp.int32)
+    nb = b.shape[-1]
+    out = None
+    for half, plane in enumerate((b & 0xF, b >> 4)):
+        place = _onehot(nb, 2 * nb, lambda r, c, h=half: c == 2 * r + h)
+        part = jax.lax.dot(decode(plane, fmt), place,
+                           preferred_element_type=jnp.float32)
+        out = part if out is None else out + part
+    return out
+
+
+def spread_scales(scales, blk: int, width: int, first=0):
+    """(rows, n) block scales -> (rows, width) per-element scales, in-
+    kernel: column ``l`` takes ``scales[:, first + l // blk]``.
+    ``first`` may be traced (a grid-step offset)."""
+    import jax
+    import jax.numpy as jnp
+
+    sel = _onehot(scales.shape[-1], width,
+                  lambda r, c: r == first + c // blk)
+    return jax.lax.dot(scales.astype(jnp.float32), sel,
+                       preferred_element_type=jnp.float32)
+
+
+# --------------------------------------------------------------------- #
 # e8m0 scale codec (1-byte block-scale exponents, OCP MX / paper Tab V)
 # --------------------------------------------------------------------- #
 # e8m0 is an 8-bit *unsigned biased exponent* with no sign or mantissa:
@@ -346,9 +403,11 @@ def e8m0_encode(scales):
 
 
 def e8m0_decode(codes):
-    """uint8 e8m0 codes -> fp32 power-of-two scales (2^(code - 127))."""
+    """uint8 e8m0 codes -> fp32 power-of-two scales (2^(code - 127)).
+    Widens through int32: Mosaic has no uint8 -> float32 cast."""
     xp = _xp(codes)
-    return xp.exp2(codes.astype(np.float32) - np.float32(E8M0_BIAS))
+    return xp.exp2(codes.astype(np.int32).astype(np.float32)
+                   - np.float32(E8M0_BIAS))
 
 
 def e8m0_scale_code(absmax, fmt_max: float):

@@ -113,8 +113,8 @@ def quantize_blockwise(w: jax.Array, fmt: str
     Returns (q (..., n) in ``fmt``, scales (..., n/BLOCK) fp32 = powers of
     two, i.e. e8m0 content — 1-byte-storable by construction).
 
-    Trace-safe end to end: sub-byte formats without a native jnp dtype
-    round via ``lowbits.quantize_values`` (pure shift/mask/exp2 — the
+    Trace-safe end to end: fp6, which has no native jnp dtype, rounds
+    via ``lowbits.quantize_values`` (pure shift/mask/exp2 — the
     RTNE arithmetic twin of ml_dtypes), not host numpy, so the whole
     function jits/vmaps.  The KV-cache twin
     (``models.attention.quantize_kv`` — can't import this module without
@@ -128,12 +128,8 @@ def quantize_blockwise(w: jax.Array, fmt: str
     wb = w.astype(jnp.float32).reshape(*lead, n // BLOCK, BLOCK)
     scales = _e8m0_scale(jnp.max(jnp.abs(wb), axis=-1), fmt_max)
     vals = wb / scales[..., None]
-    if round_dtype is not None:                # fp6/fp4: emulated formats
-        if lowbits.is_packable(fmt):           # trace-safe RTNE arithmetic
-            vals = lowbits.quantize_values(vals, fmt)
-        else:   # byte format emulated (ancient JAX w/o fp8): host rounding
-            vals = jnp.asarray(   # jaxlint: disable=JL101(host fallback for ancient JAX without native fp8 dtypes; unreachable under jit there because the whole engine already requires eager weights at build time)
-                np.asarray(vals).astype(round_dtype).astype(np.float32))
+    if round_dtype is not None:        # fp6: emulated in an e4m3 container
+        vals = lowbits.quantize_values(vals, fmt)   # trace-safe RTNE
     q = vals.astype(dtype)
     return q.reshape(*lead, n), scales
 

@@ -1,8 +1,11 @@
-"""repro.compat — capability detection, dtype-registry fallbacks,
-shard_map resolution, and the interpret-mode pallas_call path (ISSUE 1
-acceptance: the whole suite must run on a CPU-only host)."""
+"""repro.compat — capability detection, the dtype registry, the
+interpret-mode pallas_call path on the CPU, the compile-cache helper, and
+device-model detection: a missing or unknown device raises instead of
+falling back."""
 
 import functools
+import os
+import types
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +29,23 @@ def test_jax_version_tuple():
 def test_backend_platform_known():
     assert compat.backend_platform() in ("cpu", "gpu", "tpu")
     assert compat.is_tpu() == (compat.backend_platform() == "tpu")
+
+
+def test_backend_error_propagates(monkeypatch):
+    """A backend that fails to initialise must not read as a CPU run."""
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    compat.backend_platform.cache_clear()
+    monkeypatch.setattr(jax, "devices", broken)
+    try:
+        with pytest.raises(RuntimeError, match="initialize backend"):
+            compat.backend_platform()
+        with pytest.raises(RuntimeError, match="initialize backend"):
+            compat.report()
+    finally:
+        monkeypatch.undo()
+        compat.backend_platform.cache_clear()
 
 
 # --------------------------------------------------------------------- #
@@ -103,20 +123,17 @@ def test_describe_distinguishes_native_and_emulated():
 
 
 # --------------------------------------------------------------------- #
-# shard_map resolution
+# shard_map
 # --------------------------------------------------------------------- #
 
 def test_resolve_shard_map_source():
-    fn, src = compat.resolve_shard_map()
-    assert callable(fn)
-    assert src in ("jax.shard_map", "jax.experimental.shard_map")
+    assert compat.shard_map is jax.shard_map
 
 
-@pytest.mark.parametrize("check_kwarg", [{}, {"check_vma": False},
-                                         {"check_rep": False}])
+@pytest.mark.parametrize("check_kwarg", [{}, {"check_vma": False}])
 def test_shard_map_runs_with_either_check_spelling(check_kwarg):
-    """The wrapper must accept both the new (check_vma) and old
-    (check_rep) kwarg and execute on a world=1 mesh."""
+    """shard_map runs on a world=1 mesh with and without its
+    replication check (``check_vma``)."""
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("d",))
@@ -145,13 +162,26 @@ def test_shard_map_decorator_form():
 # --------------------------------------------------------------------- #
 
 def test_interpret_default_matches_platform():
-    assert compat.pallas_interpret_default() == (not compat.is_tpu())
+    assert compat.pallas_interpret_default() == (
+        compat.backend_platform() == "cpu")
 
 
 def test_tpu_compiler_params_buildable():
-    cp = compat.tpu_compiler_params(
-        dimension_semantics=("parallel", "arbitrary"))
-    assert cp is not None
+    """``dimension_semantics`` becomes ``pltpu.CompilerParams`` and the
+    call still runs (interpreted on the CPU)."""
+    from jax.experimental import pallas as pl
+
+    def kernel(x_ref, o_ref):
+        o_ref[...] = x_ref[...] * 2.0
+
+    x = jnp.arange(16, dtype=jnp.float32).reshape(2, 8)
+    out = compat.pallas_call(
+        kernel, grid=(2,),
+        in_specs=[pl.BlockSpec((1, 8), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((1, 8), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((2, 8), jnp.float32),
+        dimension_semantics=("parallel",))(x)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(x) * 2.0)
 
 
 def test_pallas_call_interpret_qmatmul_matches_reference(key):
@@ -210,3 +240,59 @@ def test_report_contents():
     assert "compat,jax=" in text
     assert "float4_e2m1fn" in text
     assert len(rep.lines()) == 2 + len(rep.formats)
+
+
+# --------------------------------------------------------------------- #
+# persistent compile cache
+# --------------------------------------------------------------------- #
+
+@pytest.fixture
+def cache_config():
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_respects_env(monkeypatch, cache_config, tmp_path):
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(compat.CACHE_ENV, str(tmp_path))
+    assert compat.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_fixed_checkout_path(monkeypatch, cache_config):
+    monkeypatch.delenv(compat.CACHE_ENV, raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(root, ".jax_cache")
+    assert compat.enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert compat.enable_compile_cache() == want       # stable per call
+
+
+# --------------------------------------------------------------------- #
+# device models by device_kind
+# --------------------------------------------------------------------- #
+
+def _fake_devices(monkeypatch, platform, kind):
+    dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+    monkeypatch.setattr(jax, "devices", lambda *a: [dev])
+
+
+def test_detect_backend_model_by_device_kind(monkeypatch):
+    from repro.core import device_model as dm
+
+    _fake_devices(monkeypatch, "tpu", "TPU v5 lite")
+    assert dm.detect_backend_model() is dm.TPU_V5E
+    _fake_devices(monkeypatch, "cpu", "cpu")
+    assert dm.detect_backend_model() is dm.HOST_CPU
+
+
+@pytest.mark.parametrize("platform,kind", [("tpu", "TPU v4"),
+                                           ("gpu", "NVIDIA H100 PCIe")])
+def test_detect_backend_model_unknown_kind_raises(monkeypatch, platform,
+                                                  kind):
+    from repro.core import device_model as dm
+
+    _fake_devices(monkeypatch, platform, kind)
+    with pytest.raises(ValueError, match="no device model"):
+        dm.detect_backend_model()

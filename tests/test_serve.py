@@ -93,3 +93,26 @@ def test_quantized_bytes_shrink(small_model):
     _, s16 = quantize_params(params, "bfloat16")
     assert s8["quantized_bytes"] < s16["quantized_bytes"]
     assert s4["quantized_bytes"] < s8["quantized_bytes"]
+
+
+@pytest.mark.parametrize("kv_format", [None, "float4_e2m1fn"])
+def test_launcher_builds_the_engine(kv_format):
+    """``launch.serve.build_engine`` (the CLI's and chip_smoke.py's
+    builder) turns parsed arguments into a working engine: the seed
+    picks the weights, --kv-format the KV store."""
+    from repro.launch import serve
+
+    argv = ["--arch", "gptneox-1b", "--reduced", "--batch", "2",
+            "--max-seq", "64", "--decode-block", "4", "--seed", "3"]
+    if kv_format:
+        argv += ["--kv-format", kv_format]
+    eng = serve.build_engine(serve.make_parser().parse_args(argv))
+    assert eng.kv_format == kv_format
+    assert (eng.model.cfg.kv_format or None) == kv_format
+    want, _ = quantize_params(eng.model.init(jax.random.PRNGKey(3)),
+                              "bfloat16")          # the default --precision
+    for a, b in zip(jax.tree.leaves(eng.params), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    eng.submit([1, 2, 3, 4, 5], max_new_tokens=6)
+    (res,) = eng.run()
+    assert res.status == "ok" and len(res.tokens) == 6

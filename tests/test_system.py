@@ -82,7 +82,6 @@ def test_dryrun_single_cell_subprocess(tmp_path):
     """The real multi-pod dry-run, smallest cell, in a subprocess (it
     forces 512 host devices)."""
     env = dict(os.environ, PYTHONPATH="src")
-    env.pop("JAX_PLATFORMS", None)
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun",
          "--arch", "seamless-m4t-medium", "--shape", "decode_32k",
