@@ -91,6 +91,21 @@ EMIT_NONE = 0      # slot inactive this step
 EMIT_TOKEN = 1     # token sampled and appended
 EMIT_FAULT = 2     # sentinel tripped: logits went non-finite
 
+# host spans (jax.profiler.TraceAnnotation) at the engine's own phase
+# boundaries.  They land in a profile beside the device's operations, on
+# one clock, so a trace can put the device's idle time down to the phase
+# the host was in; with no profiler running each costs under a
+# microsecond.  Admission opens four, a fused decode call two.
+SPAN_ADMIT = "engine.admit"            # one request: hand-over to first token
+SPAN_CLEAR_SLOT = "engine.clear_slot"  # evict the slot's previous tenant
+SPAN_PREFILL = "engine.prefill"        # the prompt's dispatches
+SPAN_FIRST_TOKEN = "engine.first_token"  # admission write + its host read
+SPAN_DECODE = "engine.decode"          # the fused-loop dispatch
+SPAN_HARVEST = "engine.harvest"        # host reads + per-slot bookkeeping
+SPANS = (SPAN_ADMIT, SPAN_CLEAR_SLOT, SPAN_PREFILL, SPAN_FIRST_TOKEN,
+         SPAN_DECODE, SPAN_HARVEST)
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclasses.dataclass
 class GenerationResult:
@@ -101,6 +116,7 @@ class GenerationResult:
     submit_t: Optional[float] = None       # engine-clock timestamps
     first_token_t: Optional[float] = None  # (None when not applicable:
     finish_t: Optional[float] = None       # e.g. shed before prefill)
+    admit_t: Optional[float] = None        # handed to a slot
 
     @property
     def truncated(self) -> bool:
@@ -112,6 +128,13 @@ class GenerationResult:
             return None
         return self.first_token_t - self.submit_t
 
+    @property
+    def queue_wait(self) -> Optional[float]:
+        """Time in the queue: submit to the hand-over to a slot."""
+        if self.submit_t is None or self.admit_t is None:
+            return None
+        return self.admit_t - self.submit_t
+
 
 @dataclasses.dataclass
 class _Request:
@@ -122,6 +145,7 @@ class _Request:
     patches: Optional[np.ndarray] = None   # VLM patch-prefix embeddings
     submit_t: float = 0.0                  # engine-clock timestamps
     deadline_s: Optional[float] = None     # absolute (engine clock)
+    admit_t: Optional[float] = None
     first_token_t: Optional[float] = None
 
     @property
@@ -571,77 +595,86 @@ class ServeEngine:
         VLM patch-embedding chunks first, token chunks after — straight
         into the pool region (jitted; quantize-on-write for kv_format
         caches; SSM conv/state carried across chunk boundaries)."""
-        self.cache = self._clear_slot_fn(self.cache, jnp.int32(slot))
-        if self._draft_cache is not None:
-            self._draft_cache = self._draft_clear_fn(self._draft_cache,
-                                                     jnp.int32(slot))
+        with _span(SPAN_CLEAR_SLOT, slot=slot):
+            self.cache = self._clear_slot_fn(self.cache, jnp.int32(slot))
+            if self._draft_cache is not None:
+                self._draft_cache = self._draft_clear_fn(
+                    self._draft_cache, jnp.int32(slot))
         cdtype = jnp.dtype(self.model.cfg.compute_dtype)
         chunk = self.prefill_chunk
-        if req.frames is not None:
-            src = req.frames.shape[0]
-            padded = np.zeros((1, self.enc_len, req.frames.shape[1]),
-                              np.float32)
-            padded[0, :src] = req.frames
-            self.cache = self._encode_slot_fn(
-                self.params, self.cache, jnp.asarray(padded, cdtype),
-                jnp.int32(slot), jnp.int32(src))
-        offset, logits = 0, None
-        if req.patches is not None:
-            n_pat = req.patches.shape[0]
-            for off in range(0, n_pat, chunk):
-                part = req.patches[off:off + chunk]
-                valid = part.shape[0]
-                padded = np.zeros((1, chunk, part.shape[1]), np.float32)
-                padded[0, :valid] = part
-                logits, self.cache = self._prefill_embeds_fn(
+        n_pat = 0 if req.patches is None else req.patches.shape[0]
+        chunks = -(-n_pat // chunk) + -(-len(req.prompt) // chunk)
+        with _span(SPAN_PREFILL, chunks=chunks):
+            if req.frames is not None:
+                src = req.frames.shape[0]
+                padded = np.zeros((1, self.enc_len, req.frames.shape[1]),
+                                  np.float32)
+                padded[0, :src] = req.frames
+                self.cache = self._encode_slot_fn(
                     self.params, self.cache, jnp.asarray(padded, cdtype),
-                    jnp.int32(slot), jnp.int32(off), jnp.int32(valid))
-            offset = n_pat
-        for off in range(0, len(req.prompt), chunk):
-            part = req.prompt[off:off + chunk]
-            valid = len(part)
-            part = part + [0] * (chunk - valid)
-            logits, self.cache = self._prefill_chunk_fn(
-                self.params, self.cache,
-                jnp.asarray(part, jnp.int32), jnp.int32(slot),
-                jnp.int32(offset + off), jnp.int32(valid))
-            if self._draft_cache is not None:
-                # the draft model shares the slot protocol: its cache is
-                # prefilled through the same chunk stream (draft-model
-                # targets are plain decoder-only, so offset == 0)
-                _, self._draft_cache = self._draft_prefill_fn(
-                    self._draft_params, self._draft_cache,
+                    jnp.int32(slot), jnp.int32(src))
+            offset, logits = 0, None
+            if req.patches is not None:
+                for off in range(0, n_pat, chunk):
+                    part = req.patches[off:off + chunk]
+                    valid = part.shape[0]
+                    padded = np.zeros((1, chunk, part.shape[1]), np.float32)
+                    padded[0, :valid] = part
+                    logits, self.cache = self._prefill_embeds_fn(
+                        self.params, self.cache, jnp.asarray(padded, cdtype),
+                        jnp.int32(slot), jnp.int32(off), jnp.int32(valid))
+                offset = n_pat
+            for off in range(0, len(req.prompt), chunk):
+                part = req.prompt[off:off + chunk]
+                valid = len(part)
+                part = part + [0] * (chunk - valid)
+                logits, self.cache = self._prefill_chunk_fn(
+                    self.params, self.cache,
                     jnp.asarray(part, jnp.int32), jnp.int32(slot),
                     jnp.int32(offset + off), jnp.int32(valid))
-        return logits
+                if self._draft_cache is not None:
+                    # the draft model shares the slot protocol: its cache
+                    # is prefilled through the same chunk stream (draft-
+                    # model targets are plain decoder-only: offset == 0)
+                    _, self._draft_cache = self._draft_prefill_fn(
+                        self._draft_params, self._draft_cache,
+                        jnp.asarray(part, jnp.int32), jnp.int32(slot),
+                        jnp.int32(offset + off), jnp.int32(valid))
+            return logits
 
     def _admit(self) -> None:
         for slot in range(self.batch):
             if self.slot_req[slot] is not None or not self.queue:
                 continue
-            req, expired = self.queue.take(self._now())
+            now = self._now()
+            req, expired = self.queue.take(now)
             for e in expired:
                 # deadline passed while queued: account it WITHOUT
                 # spending a single prefill dispatch on it
                 self._finish_unadmitted(e, "deadline_exceeded")
             if req is None:
                 continue
-            logits = self._prefill_into_slot(slot, req)
-            args = [self.state, logits, jnp.int32(slot),
-                    jnp.int32(req.trunk_len),
-                    jnp.int32(req.max_new_tokens),
-                    jnp.int32(req.request_id), self._sample_key]
-            if self.spec is not None:
-                ptail = self.spec.prompt_tail
-                tail = np.full((ptail,), -1, np.int32)
-                got = req.prompt[-ptail:]
-                if got:
-                    tail[-len(got):] = got
-                args.append(jnp.asarray(tail))
-            tok, self.state = self._admit_fn(*args)
-            self.slot_req[slot] = req
-            self.out_tokens[slot] = [int(self._host_read(tok))]
-            req.first_token_t = self._now()
+            req.admit_t = now
+            with _span(SPAN_ADMIT, request_id=req.request_id, slot=slot,
+                       prompt_len=req.trunk_len):
+                logits = self._prefill_into_slot(slot, req)
+                args = [self.state, logits, jnp.int32(slot),
+                        jnp.int32(req.trunk_len),
+                        jnp.int32(req.max_new_tokens),
+                        jnp.int32(req.request_id), self._sample_key]
+                if self.spec is not None:
+                    ptail = self.spec.prompt_tail
+                    tail = np.full((ptail,), -1, np.int32)
+                    got = req.prompt[-ptail:]
+                    if got:
+                        tail[-len(got):] = got
+                    args.append(jnp.asarray(tail))
+                with _span(SPAN_FIRST_TOKEN):
+                    tok, self.state = self._admit_fn(*args)
+                    first = int(self._host_read(tok))
+                self.slot_req[slot] = req
+                self.out_tokens[slot] = [first]
+                req.first_token_t = self._now()
             self._slot_progress[slot] = (1, self._dispatches)
             if req.max_new_tokens <= 1:
                 self._finish(slot)
@@ -906,7 +939,8 @@ class ServeEngine:
         self.results.append(GenerationResult(
             req.request_id, req.prompt, self.out_tokens[slot],
             status=status, submit_t=req.submit_t,
-            first_token_t=req.first_token_t, finish_t=self._now()))
+            first_token_t=req.first_token_t, finish_t=self._now(),
+            admit_t=req.admit_t))
         self.slot_req[slot] = None
 
     def _finish_unadmitted(self, req: _Request, status: str) -> None:
@@ -932,8 +966,9 @@ class ServeEngine:
         fn = self._loops.get(k)
         if fn is None:
             fn = self._loops[k] = self._make_decode_loop(k)
-        self.cache, self.state, toks, emitted = fn(
-            self.params, self.cache, self.state, self._sample_key)
+        with _span(SPAN_DECODE, k=k):
+            self.cache, self.state, toks, emitted = fn(
+                self.params, self.cache, self.state, self._sample_key)
         self._harvest(toks, emitted)
         return k
 
@@ -947,14 +982,15 @@ class ServeEngine:
         if fn is None:
             fn = self._spec_loops[n_blocks] = self._make_spec_loop(
                 n_blocks)
-        if self._draft_cache is not None:
-            (self.cache, self.state, toks, emitted,
-             self._draft_cache) = fn(
-                self.params, self.cache, self.state, self._sample_key,
-                self._draft_params, self._draft_cache)
-        else:
-            self.cache, self.state, toks, emitted = fn(
-                self.params, self.cache, self.state, self._sample_key)
+        with _span(SPAN_DECODE, k=k):
+            if self._draft_cache is not None:
+                (self.cache, self.state, toks, emitted,
+                 self._draft_cache) = fn(
+                    self.params, self.cache, self.state, self._sample_key,
+                    self._draft_params, self._draft_cache)
+            else:
+                self.cache, self.state, toks, emitted = fn(
+                    self.params, self.cache, self.state, self._sample_key)
         codes = self._harvest(toks, emitted)
         # engine-lifetime acceptance accounting, from the SAME synced
         # array: a (block, slot) cell counts as a run block iff any code
@@ -966,35 +1002,37 @@ class ServeEngine:
         return n_blocks * s
 
     def _harvest(self, toks, emitted) -> np.ndarray:
-        """Block-boundary host pass shared by both loop flavours: ONE
-        sync for the (rows, batch) token/code arrays, then per-slot
+        """Block-boundary host pass shared by both loop flavours: three
+        host reads -- the (rows, batch) tokens and emitted codes and the
+        slots' ``active`` flags after the block -- then per-slot
         extend/finish/fault bookkeeping.  Returns the host codes."""
-        toks = self._host_read(toks)                  # (rows, b) — ONE sync
-        emitted = self._host_read(emitted)
-        active_after = self._host_read(self.state["active"])
-        self._dispatches += 1
-        for slot in range(self.batch):
-            if self.slot_req[slot] is None:
-                continue
-            codes = emitted[:, slot]
-            self.out_tokens[slot].extend(
-                int(t) for t, e in zip(toks[:, slot], codes)
-                if e == EMIT_TOKEN)
-            if (codes == EMIT_FAULT).any():
-                self._finish(slot, status="faulted")
-                self.cache = self._clear_slot_fn(self.cache,
-                                                 jnp.int32(slot))
-                if self._draft_cache is not None:
-                    self._draft_cache = self._draft_clear_fn(
-                        self._draft_cache, jnp.int32(slot))
-            elif not active_after[slot]:
-                self._finish(slot)
-            else:
-                self._slot_progress[slot] = (len(self.out_tokens[slot]),
-                                             self._dispatches)
-        if self._deadlines_live:
-            self._expire_inflight()
-        return emitted
+        with _span(SPAN_HARVEST):
+            toks = self._host_read(toks)       # (rows, b); the first read
+            emitted = self._host_read(emitted)  # waits for the block
+            active_after = self._host_read(self.state["active"])
+            self._dispatches += 1
+            for slot in range(self.batch):
+                if self.slot_req[slot] is None:
+                    continue
+                codes = emitted[:, slot]
+                self.out_tokens[slot].extend(
+                    int(t) for t, e in zip(toks[:, slot], codes)
+                    if e == EMIT_TOKEN)
+                if (codes == EMIT_FAULT).any():
+                    self._finish(slot, status="faulted")
+                    self.cache = self._clear_slot_fn(self.cache,
+                                                     jnp.int32(slot))
+                    if self._draft_cache is not None:
+                        self._draft_cache = self._draft_clear_fn(
+                            self._draft_cache, jnp.int32(slot))
+                elif not active_after[slot]:
+                    self._finish(slot)
+                else:
+                    self._slot_progress[slot] = (len(self.out_tokens[slot]),
+                                                 self._dispatches)
+            if self._deadlines_live:
+                self._expire_inflight()
+            return emitted
 
     def spec_report(self) -> Dict:
         """Engine-lifetime speculation accounting (host totals; the
