@@ -114,8 +114,8 @@ DEFAULT_TRACED_ROOTS: Dict[str, Set[str]] = {
         "dequantize_kv",
     },
     "models/slotstate.py": {
-        "mask_rows", "masked_tree", "decode_advance", "take_row",
-        "put_row", "clear_slot",
+        "mask_rows", "masked_tree", "decode_advance", "take_layer",
+        "put_layer", "take_row", "put_row", "clear_slot",
     },
     "models/ssm.py": {"ssm_prefill_chunk"},
     "serve/quant.py": {"quantize_blockwise", "dequantize_blockwise"},

@@ -403,7 +403,8 @@ def cache_kv(cache: dict, kv_format: Optional[str], head_dim: int,
 def cache_write_decode(cache: dict, k: jax.Array, v: jax.Array,
                        pos: jax.Array,
                        kv_format: Optional[str] = None,
-                       active: Optional[jax.Array] = None) -> dict:
+                       active: Optional[jax.Array] = None,
+                       layer: Optional[jax.Array] = None) -> dict:
     """Write one (b, 1, hkv, d) k/v at per-row slot ``pos % capacity``.
 
     pos: (b,) — rows may sit at different positions (continuous batching),
@@ -413,17 +414,22 @@ def cache_write_decode(cache: dict, k: jax.Array, v: jax.Array,
     active: optional (b,) bool — rows where False keep their previous
     slot contents and ``slot_pos`` untouched (inactive pool slots inside
     the fused decode loop must not write; their incoming k/v is garbage
-    from a held-constant last_token)."""
+    from a held-constant last_token).
+
+    layer: optional traced scalar — ``cache`` is then the period-stacked
+    pool (leaves (n_periods, b, cap, ...)) and the rows land at
+    ``[layer, row, pos % cap]``: b rows written in place, the rest of
+    the pool untouched."""
     sp_arr = cache["slot_pos"]
-    b, cap = sp_arr.shape
+    b, cap = sp_arr.shape[-2:]
     slot = (pos % cap).astype(jnp.int32)
     rows = jnp.arange(b)
-    sp = sp_arr.at[rows, slot].set(
-        mask_rows(active, pos.astype(jnp.int32), sp_arr[rows, slot]))
+    idx = (rows, slot) if layer is None else (layer, rows, slot)
+    sp = sp_arr.at[idx].set(
+        mask_rows(active, pos.astype(jnp.int32), sp_arr[idx]))
 
     def put(pool, new):
-        return pool.at[rows, slot].set(
-            mask_rows(active, new, pool[rows, slot]))
+        return pool.at[idx].set(mask_rows(active, new, pool[idx]))
 
     if is_quantized_cache(cache):
         assert kv_format is not None, "quantized cache needs its kv_format"

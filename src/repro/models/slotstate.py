@@ -14,7 +14,11 @@ special cases:
    array (``enc_out``) carries the slot on axis 0.  *Inside* the period
    scan (``lax.scan`` over the period axis) the slot axis is 0, and
    :func:`take_row` / :func:`put_row` move one slot's row in and out
-   with ``slot`` traced, so one executable serves every slot.
+   with ``slot`` traced, so one executable serves every slot.  The
+   decode step instead carries the whole period-stacked pool through
+   its scan and addresses one layer with :func:`take_layer` /
+   :func:`put_layer` (``layer`` traced), so the pool is updated in
+   place rather than restacked every step.
 
 2. **Eviction** (:func:`clear_slot`) is uniform: parts with ring
    bookkeeping (a ``slot_pos`` leaf — self-attn KV *and* cross-attn KV)
@@ -24,10 +28,12 @@ special cases:
 
 3. **Decode-step advancement** (:func:`decode_advance`) is driven by a
    single ``active`` predicate: ring KV is masked *at the write site*
-   (``cache_write_decode(active=...)`` touches O(1) rows, not
-   O(capacity)); read-only parts (``cross_kv``, ``enc_out`` — written
-   once at admission) pass through untouched; every recurrent part
-   (SSM conv/state) row-selects new-vs-old via :func:`mask_rows`.
+   (``cache_write_decode(active=..., layer=...)`` writes one row per
+   slot into the pool, not O(capacity)); read-only parts (``cross_kv``,
+   ``enc_out`` — written once at admission) pass through untouched;
+   every recurrent part (SSM conv/state) row-selects new-vs-old via
+   :func:`mask_rows` and is written back at its layer — the one read
+   and write its recurrence needs anyway.
 
 Nothing here imports the mixers — attention/ssm/transformer import
 *this* module, so the protocol stays the bottom of the model stack.
@@ -80,15 +86,40 @@ def masked_tree(mask: Optional[jax.Array], new: Any, old: Any) -> Any:
     return jax.tree.map(lambda n, o: mask_rows(mask, n, o), new, old)
 
 
-def decode_advance(active: Optional[jax.Array], part: str,
-                   new: Any, old: Any) -> Any:
-    """Advance one cache part after a decode step under the protocol
-    (rule 3 above).  ``active``: (b,) bool or None (all rows live)."""
+def decode_advance(active: Optional[jax.Array], part: str, new: Any,
+                   pool: Any, layer: jax.Array) -> Any:
+    """Advance one part of the period-stacked ``pool`` after layer
+    ``layer``'s decode step under the protocol (rule 3 above).
+
+    ``new`` is what the mixer produced: for a write-site-masked part the
+    already-written pool (it is returned as is), for a recurrent part
+    the layer's new value, row-selected against the old one and put
+    back at ``layer``.  Read-only parts return ``pool`` untouched.
+    ``active``: (b,) bool or None (all rows live)."""
     if part in WRITE_SITE_MASKED:
         return new
     if part in READ_ONLY_IN_DECODE:
-        return old
-    return masked_tree(active, new, old)
+        return pool
+    return put_layer(
+        pool, masked_tree(active, new, take_layer(pool, layer)), layer)
+
+
+def take_layer(tree: Any, layer: jax.Array) -> Any:
+    """Read one layer's part tree out of the period-stacked pool (layer
+    axis 0, ``layer`` traced).  The compiler fuses the slice into its
+    consumer, so attention reads ``pool[layer]`` where it lies."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0,
+                                               keepdims=False), tree)
+
+
+def put_layer(pool: Any, part: Any, layer: jax.Array) -> Any:
+    """Inverse of :func:`take_layer`: write one layer's part tree back
+    into the pool in place."""
+    return jax.tree.map(
+        lambda p, r: jax.lax.dynamic_update_index_in_dim(
+            p, r.astype(p.dtype), layer, 0),
+        pool, part)
 
 
 def take_row(tree: Any, slot: jax.Array) -> Any:

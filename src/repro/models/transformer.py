@@ -481,6 +481,14 @@ def lm_decode_step(params: dict, cache: dict, token: jax.Array,
     continuous batching; pass a broadcast scalar for lockstep decode).
     Returns (logits (b, vocab), updated cache).
 
+    The period-stacked pool is carried through the layer scan, not
+    scanned over: period ``l`` reads its parts at ``pool[l]`` (the
+    compiler fuses the read into attention and the recurrence) and
+    writes only what changed — one ring-KV row per slot at ``[l, row,
+    pos % cap]``, the recurrent state's new value at ``[l]``.  Inside
+    the fused decode loop, whose jit donates the pool, the whole step
+    therefore updates the pool in place: no layer or pool is copied.
+
     ``active`` (optional (b,) bool) masks *all* cache mutation through
     the slot-state protocol (``repro.models.slotstate.decode_advance``):
     ring KV is masked at the write site, cross-KV/enc_out are read-only,
@@ -499,12 +507,13 @@ def lm_decode_step(params: dict, cache: dict, token: jax.Array,
         pos = jnp.broadcast_to(pos, token.shape)
     positions = pos[:, None]                          # (b, 1)
 
-    def period_fn(x, scanned):
-        period_params, period_cache = scanned
-        new_cache = {}
+    def period_fn(carry, scanned):
+        x, pool = carry
+        period_params, l = scanned
+        new_pool = {}
         for i, blk in enumerate(pattern):
             p = period_params[f"pos{i}"]
-            c = period_cache[f"pos{i}"]
+            c = pool[f"pos{i}"]
             kv_fmt = cfg.kv_format_for(i)
             new_parts = {}
             if blk.mixer == "attn":
@@ -513,36 +522,37 @@ def lm_decode_step(params: dict, cache: dict, token: jax.Array,
                 k, v = attn.project_kv(p["attn"], h)
                 q = apply_rope(q, positions, cfg.rope_theta)
                 k = apply_rope(k, positions, cfg.rope_theta)
-                kv = attn.cache_write_decode(c["kv"], k, v, pos,
-                                             kv_format=kv_fmt,
-                                             active=active)
+                new_parts["kv"] = attn.cache_write_decode(
+                    c["kv"], k, v, pos, kv_format=kv_fmt, active=active,
+                    layer=l)
+                kv = slotstate.take_layer(new_parts["kv"], l)
                 kc, vc = attn.cache_kv(kv, kv_fmt, cfg.head_dim,
                                        out_dtype=x.dtype)
                 o = attn.decode_attention(
                     q, kc, vc, kv["slot_pos"], pos,
                     window=blk.window, softcap=cfg.attn_logit_softcap)
                 x = x + attn.project_out(p["attn"], o)
-                new_parts["kv"] = kv
                 if blk.cross_attn and "cross_kv" in c:
                     h = rms_norm(p["ln_cross"], x, cfg.norm_eps)
                     q = attn.project_q(p["cross"], h)
-                    ck, cv = attn.cache_kv(c["cross_kv"], kv_fmt,
-                                           cfg.head_dim, out_dtype=x.dtype)
+                    ckv = slotstate.take_layer(c["cross_kv"], l)
+                    ck, cv = attn.cache_kv(ckv, kv_fmt, cfg.head_dim,
+                                           out_dtype=x.dtype)
                     # every valid source slot is visible (slot_pos >= 0
                     # masks padding); a huge query position makes the
                     # causal comparison vacuous
                     o = attn.cache_attention(
-                        q, ck, cv, c["cross_kv"]["slot_pos"],
+                        q, ck, cv, ckv["slot_pos"],
                         jnp.full_like(positions, jnp.int32(2 ** 30)))
                     x = x + attn.project_out(p["cross"], o)
                     new_parts["cross_kv"] = c["cross_kv"]
             elif blk.mixer == "ssm":
                 h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
-                out, new_parts["ssm"] = ssm_lib.ssm_decode(p["ssm"], h,
-                                                           c["ssm"], cfg)
+                out, new_parts["ssm"] = ssm_lib.ssm_decode(
+                    p["ssm"], h, slotstate.take_layer(c["ssm"], l), cfg)
                 x = x + out
             entry = {part: slotstate.decode_advance(active, part, new,
-                                                    c[part])
+                                                    c[part], l)
                      for part, new in new_parts.items()}
             if blk.ffn == "dense":
                 h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
@@ -551,12 +561,13 @@ def lm_decode_step(params: dict, cache: dict, token: jax.Array,
                 h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
                 y, _ = moe_lib.apply_moe(p["moe"], h, cfg)
                 x = x + y
-            new_cache[f"pos{i}"] = entry
-        return x, new_cache
+            new_pool[f"pos{i}"] = entry
+        return (x, new_pool), None
 
     layer_cache = {k: v for k, v in cache.items() if k.startswith("pos")}
-    x, new_layer_cache = jax.lax.scan(
-        period_fn, x, (params["layers"], layer_cache))
+    (x, new_layer_cache), _ = jax.lax.scan(
+        period_fn, (x, layer_cache),
+        (params["layers"], jnp.arange(cfg.n_periods, dtype=jnp.int32)))
     out_cache = dict(new_layer_cache)
     if enc_out is not None:
         out_cache["enc_out"] = enc_out

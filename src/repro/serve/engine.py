@@ -686,6 +686,13 @@ class ServeEngine:
         emitted-codes (k, b) int32 — EMIT_NONE/TOKEN/FAULT) plus the
         advanced cache/state.
 
+        The cache (argument 1) is donated and carried through the scan;
+        each step writes only the rows and states it changes
+        (``lm_decode_step``), so the returned cache aliases the one
+        passed in and no pool is copied, per step or per call.  The
+        caller must drop its reference to the cache it passed: those
+        buffers are deleted by the call.
+
         Two robustness legs ride inside the body at zero marginal sync:
 
         * **Fault injection** — if the slot's armed ``fault_pos`` equals
@@ -745,8 +752,8 @@ class ServeEngine:
             return cache, state, toks, emitted
 
         if self.mesh is None:
-            return jax.jit(loop)
-        return jax.jit(loop, out_shardings=(
+            return jax.jit(loop, donate_argnums=(1,))
+        return jax.jit(loop, donate_argnums=(1,), out_shardings=(
             self._sh["cache"], self._sh["state"],
             self._sh["replicated"], self._sh["replicated"]))
 

@@ -198,3 +198,27 @@ def test_state_lives_on_device(small_model):
     eng.decode_loop()                            # one fused dispatch
     assert len(eng.results) == 1                 # 1 admit + 8 fused >= 8
     assert len(eng.results[0].tokens) == 8
+
+
+@pytest.mark.parametrize("arch", ["gptneox-1b", "mamba2-2.7b"])
+def test_decode_loop_donates_pool(arch):
+    """The fused loop consumes the pool it is given (KV rows or SSM
+    state): after a dispatch the previous pool's buffers are deleted,
+    and the next admission and decode, on the pool the loop returned,
+    serve the same tokens as per-step dispatch."""
+    model = build_model(get_config(arch).reduced())
+    params = model.init(jax.random.PRNGKey(2))
+    outs = []
+    for block in (4, 1):
+        eng = ServeEngine(model, params, batch=2, max_seq=64,
+                          decode_block=block, prefill_chunk=4)
+        eng.submit([1, 2, 3, 4, 5, 6], max_new_tokens=12)
+        eng.decode_loop()                        # admit, first block
+        before = jax.tree.leaves(eng.cache)
+        eng.decode_loop()
+        assert all(a.is_deleted() for a in before)
+        assert not any(a.is_deleted() for a in jax.tree.leaves(eng.cache))
+        eng.submit([9, 8, 7], max_new_tokens=5)
+        outs.append(_tokens(eng.run()))
+    assert outs[0] == outs[1]
+    assert [len(t) for t in outs[0]] == [12, 5]

@@ -15,6 +15,7 @@ absent chip cannot be read back from it.
 import dataclasses
 import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -57,8 +58,8 @@ def _on(sharding, tree):
         tree)
 
 
-def _model(kv_format=None):
-    cfg = get_config("gptneox-1b")
+def _model(kv_format=None, arch="gptneox-1b"):
+    cfg = get_config(arch)
     if kv_format:
         cfg = dataclasses.replace(cfg, kv_format=kv_format)
     return build_model(cfg)
@@ -96,6 +97,52 @@ def test_prefill_chunk_compiles_at_full_width(one_chip):
         _on(one_chip, params), _on(one_chip, cache), tokens, i32, i32,
         i32).compile()
     assert _fits(compiled) > 2e9
+
+
+def _loop_engine(model):
+    """The engine's own methods on an engine that holds no arrays."""
+    from repro.serve import ServeEngine
+    engine = object.__new__(ServeEngine)
+    engine.model, engine.batch, engine.max_seq = model, BATCH, MAX_SEQ
+    engine._temperature, engine._top_k = 0.0, 0
+    engine.spec, engine.mesh, engine._sh = None, None, None
+    return engine
+
+
+def _body_copied_shapes(hlo: str) -> set:
+    """Shapes ``d0,d1,...`` of every ``copy`` in optimized HLO text
+    outside the entry computation, i.e. inside the loops, per step."""
+    body = re.sub(r"^ENTRY .*?^}$", "", hlo, flags=re.M | re.S)
+    return set(re.findall(r"= \w+\[([\d,]*)\]\S* copy\(", body))
+
+
+@pytest.mark.parametrize("arch,kv_format", [
+    ("gptneox-1b", None), ("gptneox-1b", "float4_e2m1fn"),
+    ("mamba2-2.7b", None)])
+def test_decode_loop_updates_pool_in_place(one_chip, arch, kv_format):
+    """The fused loop donates the slot pool and carries it through the
+    layer scan: its output aliases the whole pool and no step copies
+    anything pool-shaped.  A dense pool (bf16 KV, SSM state) is then
+    never held twice: the temporaries stay far below one pool.  The
+    packed fp4 pool is exempt from that bound: the loop keeps it in a
+    layout of its own, into which the entry converts it once per call,
+    and its XLA path dequantizes a layer's K/V per step."""
+    model = _model(kv_format, arch)
+    engine = _loop_engine(model)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_cache(BATCH, MAX_SEQ))
+    state = jax.eval_shape(engine._init_state)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    compiled = engine._make_decode_loop(2).lower(
+        *_on(one_chip, (params, cache, state, key))).compile()
+    pool = jax.tree.leaves(cache)
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in pool)
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == pool_bytes
+    pool_shapes = {",".join(map(str, a.shape)) for a in pool}
+    assert not _body_copied_shapes(compiled.as_text()) & pool_shapes
+    if kv_format is None:
+        assert ma.temp_size_in_bytes < pool_bytes / 4
 
 
 def _kernel_case(name):
