@@ -60,6 +60,7 @@ __all__ = [
     "packed_spec",
     "storage_bytes_per_element",
     "shard_map",
+    "context_mesh",
     "pallas_interpret_default",
     "pallas_call",
     "enable_compile_cache",
@@ -221,6 +222,18 @@ def storage_bytes_per_element(name: str, packed: bool = True) -> float:
 
 # ``jax.shard_map`` (its replication check is spelled ``check_vma``)
 shard_map = jax.shard_map
+
+
+def context_mesh():
+    """The mesh of the enclosing ``with mesh:`` block (what bare
+    ``PartitionSpec`` sharding constraints resolve against), else the
+    one ``jax.set_mesh`` installed, else None.  Usable while tracing."""
+    from jax._src import mesh as mesh_lib
+    mesh = mesh_lib.thread_resources.env.physical_mesh
+    if not mesh.empty:
+        return mesh
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 # --------------------------------------------------------------------- #

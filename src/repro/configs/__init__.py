@@ -1,5 +1,6 @@
 """Config registry: 10 assigned architectures (+ the paper's GPT-NeoX case
-study), 4 benchmark shapes, and the (arch x shape) applicability matrix."""
+study and Granite 4.0-H Small), 4 benchmark shapes, and the (arch x shape)
+applicability matrix."""
 
 from __future__ import annotations
 
@@ -29,6 +30,7 @@ from repro.configs.kimi_k2_1t import CONFIG as KIMI_K2
 from repro.configs.llama4_maverick_400b import CONFIG as LLAMA4_MAVERICK
 from repro.configs.internvl2_2b import CONFIG as INTERNVL2_2B
 from repro.configs.gptneox_1b import CONFIG as GPTNEOX_1B
+from repro.configs.granite4_h_small import CONFIG as GRANITE4_H_SMALL
 
 # The 10 assigned architectures, in the task-spec order.
 ASSIGNED: Tuple[ArchConfig, ...] = (
@@ -46,6 +48,7 @@ ASSIGNED: Tuple[ArchConfig, ...] = (
 
 REGISTRY: Dict[str, ArchConfig] = {c.name: c for c in ASSIGNED}
 REGISTRY[GPTNEOX_1B.name] = GPTNEOX_1B
+REGISTRY[GRANITE4_H_SMALL.name] = GRANITE4_H_SMALL
 
 
 def get_config(name: str) -> ArchConfig:

@@ -47,8 +47,16 @@ class ArchConfig:
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
     qkv_bias: bool = False
+    # granite scaling: x = embedding_multiplier * E[t]; every sublayer
+    # adds residual_multiplier * its output; logits / logits_scaling.
+    # Trace-time constants: at 1.0 they emit no operation.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # attention flavor
     rope_theta: float = 10000.0
+    attn_scale: Optional[float] = None     # None = 1/sqrt(head_dim)
+    use_rope: bool = True         # False = no positional encoding (NoPE)
     attn_logit_softcap: Optional[float] = None
     final_logit_softcap: Optional[float] = None
     sliding_window: Optional[int] = None   # used by blocks with window
@@ -59,9 +67,14 @@ class ArchConfig:
     moe_every: int = 1            # MoE FFN every k-th block (1 = all blocks)
     moe_d_ff: int = 0             # per-expert hidden dim (0 = use d_ff)
     moe_shared_expert: bool = False
-    moe_capacity_factor: float = 1.25
-    # hybrid (jamba): attention block every k-th block, SSM otherwise
+    moe_shared_d_ff: int = 0      # shared expert's hidden dim (0 = expert's)
+    # expert parallelism: the router scores all moe_num_experts experts;
+    # this chip holds the first moe_experts_held of them (0 = all) and
+    # computes their part alone
+    moe_experts_held: int = 0
+    # hybrid: attention block every k-th block, SSM otherwise
     attn_every: int = 1           # 1 = all attention; 8 = jamba 1:7
+    attn_offset: int = 0          # attention's position in the period
     # SSM (mamba2 SSD)
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -137,6 +150,14 @@ class ArchConfig:
     def expert_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
 
+    @property
+    def shared_d_ff(self) -> int:
+        return self.moe_shared_d_ff or self.expert_d_ff
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_experts_held or self.moe_num_experts
+
     def kv_format_for(self, pos_in_period: int) -> Optional[str]:
         """Effective KV format for one position-in-period (None = plain).
 
@@ -167,8 +188,9 @@ class ArchConfig:
             if self.family == "ssm":
                 mixer: str = "ssm"
             elif self.attn_every > 1:
-                # hybrid: attention at position 0 of each period, SSM else
-                mixer = "attn" if i % self.attn_every == 0 else "ssm"
+                # hybrid: attention at attn_offset of each period, SSM else
+                mixer = ("attn" if i % self.attn_every == self.attn_offset
+                         else "ssm")
             else:
                 mixer = "attn"
             window = None
@@ -229,11 +251,11 @@ class ArchConfig:
                 b += mult * self.d_model * self.d_ff + self.d_model
             elif blk.ffn == "moe":
                 mult = 3 if self.mlp_variant in ("swiglu", "geglu") else 2
-                b += (self.moe_num_experts * mult * self.d_model
+                b += (self.experts_held * mult * self.d_model
                       * self.expert_d_ff)
                 b += self.d_model * self.moe_num_experts   # router
                 if self.moe_shared_expert:
-                    b += mult * self.d_model * self.expert_d_ff
+                    b += mult * self.d_model * self.shared_d_ff
                 b += self.d_model
             n += b * self.n_periods
         if self.is_encoder_decoder:
@@ -253,7 +275,7 @@ class ArchConfig:
         mult = 3 if self.mlp_variant in ("swiglu", "geglu") else 2
         expert = mult * self.d_model * self.expert_d_ff
         inactive_per_moe_block = (
-            (self.moe_num_experts - self.moe_top_k) * expert)
+            max(self.experts_held - self.moe_top_k, 0) * expert)
         n_moe_blocks = sum(1 for b in self.block_pattern()
                            if b.ffn == "moe") * self.n_periods
         return self.param_count() - inactive_per_moe_block * n_moe_blocks
@@ -276,6 +298,8 @@ class ArchConfig:
             moe_num_experts=min(self.moe_num_experts, 4),
             moe_top_k=min(self.moe_top_k, 2),
             moe_d_ff=64 if self.moe_d_ff else 0,
+            moe_shared_d_ff=96 if self.moe_shared_d_ff else 0,
+            moe_experts_held=0,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_head_dim=16 if self.ssm_state else 64,
             ssm_chunk=32,

@@ -128,7 +128,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
                                accum_dtype="bfloat16" if cfg.fsdp
                                else "float32")
         metric_keys = ("loss", "ce", "acc", "moe_lb_loss", "moe_z_loss",
-                       "moe_dropped", "grad_norm")
+                       "grad_norm")
         out_shardings = (state_shardings,
                          {k: _specs_to_shardings(mesh, P())
                           for k in metric_keys})

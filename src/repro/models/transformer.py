@@ -26,12 +26,13 @@ from repro.models import moe as moe_lib
 from repro.models import slotstate
 from repro.models import ssm as ssm_lib
 from repro.models.layers import (
-    apply_mlp, dense_init, embed, init_mlp, init_rms_norm, rms_norm, unembed)
+    apply_mlp, apply_rope, dense_init, embed, init_mlp, init_rms_norm,
+    rms_norm, unembed)
 
 # Number of vision patches the VLM frontend stub contributes to the trunk.
 VLM_PATCHES = 256
 
-AUX_KEYS = ("moe_lb_loss", "moe_z_loss", "moe_dropped")
+AUX_KEYS = ("moe_lb_loss", "moe_z_loss")
 
 
 def _shard_batch(x: jax.Array, cfg: ArchConfig) -> jax.Array:
@@ -56,6 +57,60 @@ def _acc_aux(acc, new):
     for k, v in new.items():
         out[k] = out[k] + v
     return out
+
+
+# The configuration's scaling, positions and attention scale are
+# trace-time constants: at their defaults (multipliers 1, rotary on,
+# scale 1/sqrt(head_dim)) these helpers emit exactly the operations the
+# unscaled block always did.
+
+def _embed(params: dict, tokens: jax.Array, cfg: ArchConfig) -> jax.Array:
+    x = embed(params["embed"], tokens)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x.astype(jnp.dtype(cfg.compute_dtype))
+
+
+def _rope(x: jax.Array, positions: jax.Array, cfg: ArchConfig
+          ) -> jax.Array:
+    return apply_rope(x, positions, cfg.rope_theta) if cfg.use_rope else x
+
+
+def _residual(x: jax.Array, y: jax.Array, cfg: ArchConfig) -> jax.Array:
+    """x + residual_multiplier * y: one sublayer's residual add."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+    return x + y
+
+
+def _final(params: dict, x: jax.Array, cfg: ArchConfig) -> jax.Array:
+    """The trunk's output features: final norm, over logits_scaling
+    (dividing the features divides the logits the unembedding makes)."""
+    x = rms_norm(params["final_norm"], x, cfg.norm_eps)
+    if cfg.logits_scaling != 1.0:
+        x = x / jnp.asarray(cfg.logits_scaling, x.dtype)
+    return x
+
+
+def _head(params: dict, x: jax.Array, cfg: ArchConfig) -> jax.Array:
+    """Logits (fp32) from the last block's hidden state."""
+    return unembed(unembed_weight(params, cfg), _final(params, x, cfg),
+                   softcap=cfg.final_logit_softcap)
+
+
+def _ffn(p: dict, blk: BlockSpec, x: jax.Array, cfg: ArchConfig
+         ) -> Tuple[jax.Array, dict]:
+    """The block's FFN sublayer (dense MLP or MoE on the normed input),
+    residual included.  Returns (x, aux)."""
+    if blk.ffn == "none":
+        return x, {}
+    with jax.named_scope("mlp" if blk.ffn == "dense" else "moe"):
+        h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
+        if blk.ffn == "dense":
+            y, aux = apply_mlp(p["mlp"], h, cfg.mlp_variant), {}
+        else:
+            y, aux = moe_lib.apply_moe(p["moe"], h, cfg)
+        return _residual(x, y, cfg), aux
 
 
 # --------------------------------------------------------------------- #
@@ -92,9 +147,7 @@ def _self_attention_train(p, x, cfg: ArchConfig, blk: BlockSpec,
     positions = jnp.arange(x.shape[1])
     q = attn.project_q(p, x)
     k, v = attn.project_kv(p, x)
-    from repro.models.layers import apply_rope
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q, k = _rope(q, positions, cfg), _rope(k, positions, cfg)
     ka, va = k, v
     if cfg.attn_repeat_kv and cfg.n_kv_heads < cfg.n_heads:
         g = cfg.n_heads // cfg.n_kv_heads
@@ -109,7 +162,7 @@ def _self_attention_train(p, x, cfg: ArchConfig, blk: BlockSpec,
         q = jax.lax.with_sharding_constraint(
             q, P(b_ax, "model", None, None))
     o = attn.attention(q, ka, va, causal=causal, window=blk.window,
-                       softcap=cfg.attn_logit_softcap,
+                       softcap=cfg.attn_logit_softcap, scale=cfg.attn_scale,
                        chunk=cfg.attn_chunk, k_valid=k_valid)
     if cfg.attn_seq_shard and cfg.batch_axes:
         from jax.sharding import PartitionSpec as P
@@ -132,29 +185,22 @@ def apply_block(p: dict, blk: BlockSpec, cfg: ArchConfig, x: jax.Array,
 
     ``k_valid`` (b, s) masks padded key positions in self-attention
     (pooled encoder batches pad frames to a fixed enc_len)."""
-    aux: Dict[str, jax.Array] = {}
     x = _shard_batch(x, cfg)
-    if blk.mixer == "attn":
-        h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
-        x = x + _self_attention_train(p["attn"], h, cfg, blk, causal=causal,
-                                      k_valid=k_valid)
-        if blk.cross_attn and enc_out is not None:
-            h = rms_norm(p["ln_cross"], x, cfg.norm_eps)
-            q = attn.project_q(p["cross"], h)
-            k, v = attn.project_kv(p["cross"], enc_out)
-            o = attn.attention(q, k, v, causal=False)
-            x = x + attn.project_out(p["cross"], o)
-    elif blk.mixer == "ssm":
-        h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
-        x = x + ssm_lib.ssm_forward(p["ssm"], h, cfg)
-    if blk.ffn == "dense":
-        h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
-        x = x + apply_mlp(p["mlp"], h, cfg.mlp_variant)
-    elif blk.ffn == "moe":
-        h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
-        y, aux = moe_lib.apply_moe(p["moe"], h, cfg)
-        x = x + y
-    return x, aux
+    with jax.named_scope(blk.mixer):
+        if blk.mixer == "attn":
+            h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
+            x = _residual(x, _self_attention_train(
+                p["attn"], h, cfg, blk, causal=causal, k_valid=k_valid), cfg)
+            if blk.cross_attn and enc_out is not None:
+                h = rms_norm(p["ln_cross"], x, cfg.norm_eps)
+                q = attn.project_q(p["cross"], h)
+                k, v = attn.project_kv(p["cross"], enc_out)
+                o = attn.attention(q, k, v, causal=False, scale=cfg.attn_scale)
+                x = _residual(x, attn.project_out(p["cross"], o), cfg)
+        elif blk.mixer == "ssm":
+            h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
+            x = _residual(x, ssm_lib.ssm_forward(p["ssm"], h, cfg), cfg)
+    return _ffn(p, blk, x, cfg)
 
 
 # --------------------------------------------------------------------- #
@@ -236,7 +282,7 @@ def encode(params: dict, frames: jax.Array, cfg: ArchConfig,
 def trunk_inputs(params: dict, cfg: ArchConfig, batch: Dict[str, jax.Array]
                  ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Token embeddings (+ modality fusion) and optional encoder output."""
-    x = embed(params["embed"], batch["tokens"])
+    x = _embed(params, batch["tokens"], cfg)
     enc_out = None
     if cfg.frontend == "vision" and "patches" in batch:
         x = jnp.concatenate(
@@ -248,8 +294,9 @@ def trunk_inputs(params: dict, cfg: ArchConfig, batch: Dict[str, jax.Array]
 
 def lm_features(params: dict, batch: Dict[str, jax.Array], cfg: ArchConfig
                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Trunk output after the final norm, BEFORE unembedding:
-    (features (b, s_trunk, d) at compute dtype, aux losses).
+    """Trunk output after the final norm (over ``logits_scaling``),
+    BEFORE unembedding: (features (b, s_trunk, d) at compute dtype, aux
+    losses).
 
     The training loss consumes features + :func:`unembed_weight` and
     projects to vocab in sequence chunks — materializing the full fp32
@@ -268,8 +315,7 @@ def lm_features(params: dict, batch: Dict[str, jax.Array], cfg: ArchConfig
 
     (x, aux), _ = jax.lax.scan(_remat_wrap(period_fn, cfg),
                                (x, _zero_aux()), params["layers"])
-    x = rms_norm(params["final_norm"], x, cfg.norm_eps)
-    return x, aux
+    return _final(params, x, cfg), aux
 
 
 def unembed_weight(params: dict, cfg: ArchConfig) -> jax.Array:
@@ -280,9 +326,8 @@ def lm_forward(params: dict, batch: Dict[str, jax.Array], cfg: ArchConfig
                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Returns (logits (b, s_trunk, vocab) fp32, aux losses)."""
     x, aux = lm_features(params, batch, cfg)
-    logits = unembed(unembed_weight(params, cfg), x,
-                     softcap=cfg.final_logit_softcap)
-    return logits, aux
+    return unembed(unembed_weight(params, cfg), x,
+                   softcap=cfg.final_logit_softcap), aux
 
 
 # --------------------------------------------------------------------- #
@@ -413,50 +458,46 @@ def lm_prefill(params: dict, batch: Dict[str, jax.Array], cfg: ArchConfig,
             p = period_params[f"pos{i}"]
             entry = {}
             kv_fmt = cfg.kv_format_for(i)
-            if blk.mixer == "attn":
-                h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
-                out, (k, v) = _self_attention_train(
-                    p["attn"], h, cfg, blk, return_kv=True)
-                x = x + out
-                cap = attn.cache_capacity(max_seq, blk.window)
-                kv0 = attn.init_kv_cache(x.shape[0], cap, cfg.n_kv_heads,
-                                         cfg.head_dim, k.dtype,
-                                         kv_format=kv_fmt)
-                entry["kv"] = attn.cache_write_prefill(kv0, k, v,
+            with jax.named_scope(blk.mixer):
+                if blk.mixer == "attn":
+                    h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
+                    out, (k, v) = _self_attention_train(
+                        p["attn"], h, cfg, blk, return_kv=True)
+                    x = _residual(x, out, cfg)
+                    cap = attn.cache_capacity(max_seq, blk.window)
+                    kv0 = attn.init_kv_cache(x.shape[0], cap, cfg.n_kv_heads,
+                                             cfg.head_dim, k.dtype,
+                                             kv_format=kv_fmt)
+                    entry["kv"] = attn.cache_write_prefill(kv0, k, v,
+                                                           kv_format=kv_fmt)
+                    if blk.cross_attn and enc_out is not None:
+                        h = rms_norm(p["ln_cross"], x, cfg.norm_eps)
+                        q = attn.project_q(p["cross"], h)
+                        ck, cv = attn.project_kv(p["cross"], enc_out)
+                        # cross-KV is a ring cache like self-attn KV:
+                        # quantize-on-write (kv_fmt), slot_pos = source
+                        # positions; the prompt attends the CACHED view so
+                        # prefill, chunked prefill, and decode all read the
+                        # same (possibly dequantized) cross keys
+                        ckv0 = attn.init_kv_cache(
+                            x.shape[0], enc_out.shape[1], cfg.n_kv_heads,
+                            cfg.head_dim, k.dtype, kv_format=kv_fmt)
+                        ckv = attn.cache_write_prefill(ckv0, ck, cv,
                                                        kv_format=kv_fmt)
-                if blk.cross_attn and enc_out is not None:
-                    h = rms_norm(p["ln_cross"], x, cfg.norm_eps)
-                    q = attn.project_q(p["cross"], h)
-                    ck, cv = attn.project_kv(p["cross"], enc_out)
-                    # cross-KV is a ring cache like self-attn KV:
-                    # quantize-on-write (kv_fmt), slot_pos = source
-                    # positions; the prompt attends the CACHED view so
-                    # prefill, chunked prefill, and decode all read the
-                    # same (possibly dequantized) cross keys
-                    ckv0 = attn.init_kv_cache(
-                        x.shape[0], enc_out.shape[1], cfg.n_kv_heads,
-                        cfg.head_dim, k.dtype, kv_format=kv_fmt)
-                    ckv = attn.cache_write_prefill(ckv0, ck, cv,
-                                                   kv_format=kv_fmt)
-                    kc, vc = attn.cache_kv(ckv, kv_fmt, cfg.head_dim,
-                                           out_dtype=x.dtype)
-                    o = attn.attention(q, kc, vc, causal=False)
-                    x = x + attn.project_out(p["cross"], o)
-                    entry["cross_kv"] = ckv
-            elif blk.mixer == "ssm":
-                h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
-                out, ssm_cache = ssm_lib.ssm_forward(
-                    p["ssm"], h, cfg, return_state=True)
-                x = x + out
-                entry["ssm"] = ssm_cache
-            if blk.ffn == "dense":
-                h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
-                x = x + apply_mlp(p["mlp"], h, cfg.mlp_variant)
-            elif blk.ffn == "moe":
-                h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
-                y, a = moe_lib.apply_moe(p["moe"], h, cfg)
-                x = x + y
-                aux = _acc_aux(aux, a)
+                        kc, vc = attn.cache_kv(ckv, kv_fmt, cfg.head_dim,
+                                               out_dtype=x.dtype)
+                        o = attn.attention(q, kc, vc, causal=False,
+                                           scale=cfg.attn_scale)
+                        x = _residual(x, attn.project_out(p["cross"], o), cfg)
+                        entry["cross_kv"] = ckv
+                elif blk.mixer == "ssm":
+                    h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
+                    out, ssm_cache = ssm_lib.ssm_forward(
+                        p["ssm"], h, cfg, return_state=True)
+                    x = _residual(x, out, cfg)
+                    entry["ssm"] = ssm_cache
+            x, a = _ffn(p, blk, x, cfg)
+            aux = _acc_aux(aux, a)
             new_entries[f"pos{i}"] = entry
         return (x, aux), new_entries
 
@@ -466,10 +507,7 @@ def lm_prefill(params: dict, batch: Dict[str, jax.Array], cfg: ArchConfig,
         cache[key] = per_period[key]
     if enc_out is not None:
         cache["enc_out"] = enc_out
-    x_last = rms_norm(params["final_norm"], x[:, -1:], cfg.norm_eps)
-    w_out = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-    logits = unembed(w_out, x_last, softcap=cfg.final_logit_softcap)[:, 0]
-    return logits, cache
+    return _head(params, x[:, -1:], cfg)[:, 0], cache
 
 
 def lm_decode_step(params: dict, cache: dict, token: jax.Array,
@@ -498,10 +536,8 @@ def lm_decode_step(params: dict, cache: dict, token: jax.Array,
     EVERY arch family: finished pool slots ride along at zero state cost
     (their logits are computed but garbage, and the caller masks their
     samples)."""
-    from repro.models.layers import apply_rope
     pattern = cfg.block_pattern()
-    x = embed(params["embed"], token[:, None])        # (b, 1, d)
-    x = x.astype(jnp.dtype(cfg.compute_dtype))
+    x = _embed(params, token[:, None], cfg)           # (b, 1, d)
     enc_out = cache.get("enc_out")
     if pos.ndim == 0:
         pos = jnp.broadcast_to(pos, token.shape)
@@ -516,51 +552,46 @@ def lm_decode_step(params: dict, cache: dict, token: jax.Array,
             c = pool[f"pos{i}"]
             kv_fmt = cfg.kv_format_for(i)
             new_parts = {}
-            if blk.mixer == "attn":
-                h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
-                q = attn.project_q(p["attn"], h)
-                k, v = attn.project_kv(p["attn"], h)
-                q = apply_rope(q, positions, cfg.rope_theta)
-                k = apply_rope(k, positions, cfg.rope_theta)
-                new_parts["kv"] = attn.cache_write_decode(
-                    c["kv"], k, v, pos, kv_format=kv_fmt, active=active,
-                    layer=l)
-                kv = slotstate.take_layer(new_parts["kv"], l)
-                kc, vc = attn.cache_kv(kv, kv_fmt, cfg.head_dim,
-                                       out_dtype=x.dtype)
-                o = attn.decode_attention(
-                    q, kc, vc, kv["slot_pos"], pos,
-                    window=blk.window, softcap=cfg.attn_logit_softcap)
-                x = x + attn.project_out(p["attn"], o)
-                if blk.cross_attn and "cross_kv" in c:
-                    h = rms_norm(p["ln_cross"], x, cfg.norm_eps)
-                    q = attn.project_q(p["cross"], h)
-                    ckv = slotstate.take_layer(c["cross_kv"], l)
-                    ck, cv = attn.cache_kv(ckv, kv_fmt, cfg.head_dim,
+            with jax.named_scope(blk.mixer):
+                if blk.mixer == "attn":
+                    h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
+                    q = attn.project_q(p["attn"], h)
+                    k, v = attn.project_kv(p["attn"], h)
+                    q, k = _rope(q, positions, cfg), _rope(k, positions, cfg)
+                    new_parts["kv"] = attn.cache_write_decode(
+                        c["kv"], k, v, pos, kv_format=kv_fmt, active=active,
+                        layer=l)
+                    kv = slotstate.take_layer(new_parts["kv"], l)
+                    kc, vc = attn.cache_kv(kv, kv_fmt, cfg.head_dim,
                                            out_dtype=x.dtype)
-                    # every valid source slot is visible (slot_pos >= 0
-                    # masks padding); a huge query position makes the
-                    # causal comparison vacuous
-                    o = attn.cache_attention(
-                        q, ck, cv, ckv["slot_pos"],
-                        jnp.full_like(positions, jnp.int32(2 ** 30)))
-                    x = x + attn.project_out(p["cross"], o)
-                    new_parts["cross_kv"] = c["cross_kv"]
-            elif blk.mixer == "ssm":
-                h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
-                out, new_parts["ssm"] = ssm_lib.ssm_decode(
-                    p["ssm"], h, slotstate.take_layer(c["ssm"], l), cfg)
-                x = x + out
+                    o = attn.decode_attention(
+                        q, kc, vc, kv["slot_pos"], pos, window=blk.window,
+                        softcap=cfg.attn_logit_softcap, scale=cfg.attn_scale)
+                    x = _residual(x, attn.project_out(p["attn"], o), cfg)
+                    if blk.cross_attn and "cross_kv" in c:
+                        h = rms_norm(p["ln_cross"], x, cfg.norm_eps)
+                        q = attn.project_q(p["cross"], h)
+                        ckv = slotstate.take_layer(c["cross_kv"], l)
+                        ck, cv = attn.cache_kv(ckv, kv_fmt, cfg.head_dim,
+                                               out_dtype=x.dtype)
+                        # every valid source slot is visible (slot_pos >= 0
+                        # masks padding); a huge query position makes the
+                        # causal comparison vacuous
+                        o = attn.cache_attention(
+                            q, ck, cv, ckv["slot_pos"],
+                            jnp.full_like(positions, jnp.int32(2 ** 30)),
+                            scale=cfg.attn_scale)
+                        x = _residual(x, attn.project_out(p["cross"], o), cfg)
+                        new_parts["cross_kv"] = c["cross_kv"]
+                elif blk.mixer == "ssm":
+                    h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
+                    out, new_parts["ssm"] = ssm_lib.ssm_decode(
+                        p["ssm"], h, slotstate.take_layer(c["ssm"], l), cfg)
+                    x = _residual(x, out, cfg)
             entry = {part: slotstate.decode_advance(active, part, new,
                                                     c[part], l)
                      for part, new in new_parts.items()}
-            if blk.ffn == "dense":
-                h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
-                x = x + apply_mlp(p["mlp"], h, cfg.mlp_variant)
-            elif blk.ffn == "moe":
-                h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
-                y, _ = moe_lib.apply_moe(p["moe"], h, cfg)
-                x = x + y
+            x, _ = _ffn(p, blk, x, cfg)
             new_pool[f"pos{i}"] = entry
         return (x, new_pool), None
 
@@ -571,10 +602,7 @@ def lm_decode_step(params: dict, cache: dict, token: jax.Array,
     out_cache = dict(new_layer_cache)
     if enc_out is not None:
         out_cache["enc_out"] = enc_out
-    x = rms_norm(params["final_norm"], x, cfg.norm_eps)
-    w_out = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-    logits = unembed(w_out, x, softcap=cfg.final_logit_softcap)[:, 0]
-    return logits, out_cache
+    return _head(params, x, cfg)[:, 0], out_cache
 
 
 # --------------------------------------------------------------------- #
@@ -644,14 +672,12 @@ def lm_prefill_chunk(params: dict, cache: dict, tokens: jax.Array,
     Returns (logits (1, vocab) at the last valid position, updated
     cache).
     """
-    from repro.models.layers import apply_rope
     pattern = cfg.block_pattern()
     s = tokens.shape[0]
     if embeds is not None:
         x = embeds.astype(jnp.dtype(cfg.compute_dtype))
     else:
-        x = embed(params["embed"], tokens[None, :])       # (1, s, d)
-        x = x.astype(jnp.dtype(cfg.compute_dtype))
+        x = _embed(params, tokens[None, :], cfg)          # (1, s, d)
     positions = pos_offset + jnp.arange(s, dtype=jnp.int32)   # (s,)
     valid = jnp.arange(s) < valid_len
 
@@ -663,64 +689,59 @@ def lm_prefill_chunk(params: dict, cache: dict, tokens: jax.Array,
             c = period_cache[f"pos{i}"]
             kv_fmt = cfg.kv_format_for(i)
             entry = {}
-            if blk.mixer == "attn":
-                h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
-                q = attn.project_q(p["attn"], h)
-                k, v = attn.project_kv(p["attn"], h)
-                q = apply_rope(q, positions[None, :], cfg.rope_theta)
-                k = apply_rope(k, positions[None, :], cfg.rope_theta)
-                kv_row = slotstate.take_row(c["kv"], slot)
-                # Attend against the PRE-write history concatenated with
-                # the chunk's own raw K/V.  Writing first and attending
-                # over the ring would be wrong once a chunk wraps a
-                # sliding-window ring (capacity == window): the chunk's
-                # later writes evict positions still inside its earlier
-                # queries' windows.  The concat view keeps every position
-                # the full-prefill oracle sees — history from the cache,
-                # intra-chunk causality via the position mask — and
-                # matches lm_prefill in using the chunk's unquantized K/V
-                # for its own queries.
-                kc, vc = attn.cache_kv(kv_row, kv_fmt, cfg.head_dim,
-                                       out_dtype=x.dtype)
-                chunk_sp = jnp.where(valid, positions, -1)[None, :]
-                o = attn.cache_attention(
-                    q,
-                    jnp.concatenate([kc, k.astype(kc.dtype)], axis=1),
-                    jnp.concatenate([vc, v.astype(vc.dtype)], axis=1),
-                    jnp.concatenate([kv_row["slot_pos"], chunk_sp],
-                                    axis=1),
-                    positions[None, :], window=blk.window,
-                    softcap=cfg.attn_logit_softcap)
-                x = x + attn.project_out(p["attn"], o)
-                kv_row = attn.cache_write_chunk(kv_row, k, v, positions,
-                                                valid, kv_format=kv_fmt)
-                entry["kv"] = slotstate.put_row(c["kv"], kv_row, slot)
-                if blk.cross_attn and "cross_kv" in c:
-                    h = rms_norm(p["ln_cross"], x, cfg.norm_eps)
-                    q = attn.project_q(p["cross"], h)
-                    ckv_row = slotstate.take_row(c["cross_kv"], slot)
-                    ck, cv = attn.cache_kv(ckv_row, kv_fmt, cfg.head_dim,
+            with jax.named_scope(blk.mixer):
+                if blk.mixer == "attn":
+                    h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
+                    q = attn.project_q(p["attn"], h)
+                    k, v = attn.project_kv(p["attn"], h)
+                    q = _rope(q, positions[None, :], cfg)
+                    k = _rope(k, positions[None, :], cfg)
+                    kv_row = slotstate.take_row(c["kv"], slot)
+                    # Attend against the PRE-write history concatenated with
+                    # the chunk's own raw K/V.  Writing first and attending
+                    # over the ring would be wrong once a chunk wraps a
+                    # sliding-window ring (capacity == window): the chunk's
+                    # later writes evict positions still inside its earlier
+                    # queries' windows.  The concat view keeps every position
+                    # the full-prefill oracle sees — history from the cache,
+                    # intra-chunk causality via the position mask — and
+                    # matches lm_prefill in using the chunk's unquantized K/V
+                    # for its own queries.
+                    kc, vc = attn.cache_kv(kv_row, kv_fmt, cfg.head_dim,
                                            out_dtype=x.dtype)
+                    chunk_sp = jnp.where(valid, positions, -1)[None, :]
                     o = attn.cache_attention(
-                        q, ck, cv, ckv_row["slot_pos"],
-                        jnp.full_like(positions, jnp.int32(2 ** 30))[
-                            None, :])
-                    x = x + attn.project_out(p["cross"], o)
-                    entry["cross_kv"] = c["cross_kv"]    # read-only
-            elif blk.mixer == "ssm":
-                h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
-                ssm_row = slotstate.take_row(c["ssm"], slot)
-                out, ssm_row = ssm_lib.ssm_prefill_chunk(
-                    p["ssm"], h, ssm_row, cfg, valid, valid_len)
-                x = x + out
-                entry["ssm"] = slotstate.put_row(c["ssm"], ssm_row, slot)
-            if blk.ffn == "dense":
-                h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
-                x = x + apply_mlp(p["mlp"], h, cfg.mlp_variant)
-            elif blk.ffn == "moe":
-                h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
-                y, _ = moe_lib.apply_moe(p["moe"], h, cfg)
-                x = x + y
+                        q,
+                        jnp.concatenate([kc, k.astype(kc.dtype)], axis=1),
+                        jnp.concatenate([vc, v.astype(vc.dtype)], axis=1),
+                        jnp.concatenate([kv_row["slot_pos"], chunk_sp],
+                                        axis=1),
+                        positions[None, :], window=blk.window,
+                        softcap=cfg.attn_logit_softcap, scale=cfg.attn_scale)
+                    x = _residual(x, attn.project_out(p["attn"], o), cfg)
+                    kv_row = attn.cache_write_chunk(kv_row, k, v, positions,
+                                                    valid, kv_format=kv_fmt)
+                    entry["kv"] = slotstate.put_row(c["kv"], kv_row, slot)
+                    if blk.cross_attn and "cross_kv" in c:
+                        h = rms_norm(p["ln_cross"], x, cfg.norm_eps)
+                        q = attn.project_q(p["cross"], h)
+                        ckv_row = slotstate.take_row(c["cross_kv"], slot)
+                        ck, cv = attn.cache_kv(ckv_row, kv_fmt, cfg.head_dim,
+                                               out_dtype=x.dtype)
+                        o = attn.cache_attention(
+                            q, ck, cv, ckv_row["slot_pos"],
+                            jnp.full_like(positions, jnp.int32(2 ** 30))[
+                                None, :], scale=cfg.attn_scale)
+                        x = _residual(x, attn.project_out(p["cross"], o), cfg)
+                        entry["cross_kv"] = c["cross_kv"]    # read-only
+                elif blk.mixer == "ssm":
+                    h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
+                    ssm_row = slotstate.take_row(c["ssm"], slot)
+                    out, ssm_row = ssm_lib.ssm_prefill_chunk(
+                        p["ssm"], h, ssm_row, cfg, valid, valid_len)
+                    x = _residual(x, out, cfg)
+                    entry["ssm"] = slotstate.put_row(c["ssm"], ssm_row, slot)
+            x, _ = _ffn(p, blk, x, cfg)
             new_cache[f"pos{i}"] = entry
         return x, new_cache
 
@@ -728,9 +749,7 @@ def lm_prefill_chunk(params: dict, cache: dict, tokens: jax.Array,
     x, new_layer_cache = jax.lax.scan(
         period_fn, x, (params["layers"], layer_cache))
     x_last = jax.lax.dynamic_slice_in_dim(x, valid_len - 1, 1, axis=1)
-    x_last = rms_norm(params["final_norm"], x_last, cfg.norm_eps)
-    w_out = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-    logits = unembed(w_out, x_last, softcap=cfg.final_logit_softcap)[:, 0]
+    logits = _head(params, x_last, cfg)[:, 0]
     out_cache = dict(new_layer_cache)
     if "enc_out" in cache:
         out_cache["enc_out"] = cache["enc_out"]          # read-only
@@ -772,11 +791,9 @@ def lm_verify_chunk(params: dict, cache: dict, tokens: jax.Array,
     constant); the engine masks them at acceptance time, exactly like
     the non-speculative loop masks its samples.
     """
-    from repro.models.layers import apply_rope
     pattern = cfg.block_pattern()
     b, s = tokens.shape
-    x = embed(params["embed"], tokens)                # (b, s, d)
-    x = x.astype(jnp.dtype(cfg.compute_dtype))
+    x = _embed(params, tokens, cfg)                   # (b, s, d)
     enc_out = cache.get("enc_out")
 
     def period_fn(x, scanned):
@@ -787,65 +804,57 @@ def lm_verify_chunk(params: dict, cache: dict, tokens: jax.Array,
             c = period_cache[f"pos{i}"]
             kv_fmt = cfg.kv_format_for(i)
             leg: dict = {}
-            if blk.mixer == "attn":
-                h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
-                q = attn.project_q(p["attn"], h)
-                k, v = attn.project_kv(p["attn"], h)
-                q = apply_rope(q, positions, cfg.rope_theta)
-                k = apply_rope(k, positions, cfg.rope_theta)
-                kc, vc = attn.cache_kv(c["kv"], kv_fmt, cfg.head_dim,
-                                       out_dtype=x.dtype)
-                if attn.is_quantized_cache(c["kv"]):
-                    # the chunk's own entries must be what decode READS
-                    # after its quantize-on-write, not the raw values
-                    kd = attn.dequantize_kv(*attn.quantize_kv(k, kv_fmt),
-                                            kv_fmt, cfg.head_dim,
-                                            out_dtype=x.dtype)
-                    vd = attn.dequantize_kv(*attn.quantize_kv(v, kv_fmt),
-                                            kv_fmt, cfg.head_dim,
-                                            out_dtype=x.dtype)
-                else:
-                    kd, vd = k.astype(kc.dtype), v.astype(vc.dtype)
-                o = attn.cache_attention(
-                    q,
-                    jnp.concatenate([kc, kd], axis=1),
-                    jnp.concatenate([vc, vd], axis=1),
-                    jnp.concatenate([c["kv"]["slot_pos"],
-                                     positions.astype(jnp.int32)], axis=1),
-                    positions, window=blk.window,
-                    softcap=cfg.attn_logit_softcap)
-                x = x + attn.project_out(p["attn"], o)
-                leg["kv"] = {"k": k, "v": v}
-                if blk.cross_attn and "cross_kv" in c:
-                    h = rms_norm(p["ln_cross"], x, cfg.norm_eps)
-                    q = attn.project_q(p["cross"], h)
-                    ck, cv = attn.cache_kv(c["cross_kv"], kv_fmt,
-                                           cfg.head_dim, out_dtype=x.dtype)
+            with jax.named_scope(blk.mixer):
+                if blk.mixer == "attn":
+                    h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
+                    q = attn.project_q(p["attn"], h)
+                    k, v = attn.project_kv(p["attn"], h)
+                    q, k = _rope(q, positions, cfg), _rope(k, positions, cfg)
+                    kc, vc = attn.cache_kv(c["kv"], kv_fmt, cfg.head_dim,
+                                           out_dtype=x.dtype)
+                    if attn.is_quantized_cache(c["kv"]):
+                        # the chunk's own entries must be what decode READS
+                        # after its quantize-on-write, not the raw values
+                        kd = attn.dequantize_kv(*attn.quantize_kv(k, kv_fmt),
+                                                kv_fmt, cfg.head_dim,
+                                                out_dtype=x.dtype)
+                        vd = attn.dequantize_kv(*attn.quantize_kv(v, kv_fmt),
+                                                kv_fmt, cfg.head_dim,
+                                                out_dtype=x.dtype)
+                    else:
+                        kd, vd = k.astype(kc.dtype), v.astype(vc.dtype)
                     o = attn.cache_attention(
-                        q, ck, cv, c["cross_kv"]["slot_pos"],
-                        jnp.full_like(positions, jnp.int32(2 ** 30)))
-                    x = x + attn.project_out(p["cross"], o)
-            elif blk.mixer == "ssm":
-                h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
-                out, leg["ssm"] = ssm_lib.ssm_verify_chunk(p["ssm"], h,
-                                                           c["ssm"], cfg)
-                x = x + out
-            if blk.ffn == "dense":
-                h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
-                x = x + apply_mlp(p["mlp"], h, cfg.mlp_variant)
-            elif blk.ffn == "moe":
-                h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
-                y, _ = moe_lib.apply_moe(p["moe"], h, cfg)
-                x = x + y
+                        q,
+                        jnp.concatenate([kc, kd], axis=1),
+                        jnp.concatenate([vc, vd], axis=1),
+                        jnp.concatenate([c["kv"]["slot_pos"],
+                                         positions.astype(jnp.int32)], axis=1),
+                        positions, window=blk.window,
+                        softcap=cfg.attn_logit_softcap, scale=cfg.attn_scale)
+                    x = _residual(x, attn.project_out(p["attn"], o), cfg)
+                    leg["kv"] = {"k": k, "v": v}
+                    if blk.cross_attn and "cross_kv" in c:
+                        h = rms_norm(p["ln_cross"], x, cfg.norm_eps)
+                        q = attn.project_q(p["cross"], h)
+                        ck, cv = attn.cache_kv(c["cross_kv"], kv_fmt,
+                                               cfg.head_dim, out_dtype=x.dtype)
+                        o = attn.cache_attention(
+                            q, ck, cv, c["cross_kv"]["slot_pos"],
+                            jnp.full_like(positions, jnp.int32(2 ** 30)),
+                            scale=cfg.attn_scale)
+                        x = _residual(x, attn.project_out(p["cross"], o), cfg)
+                elif blk.mixer == "ssm":
+                    h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
+                    out, leg["ssm"] = ssm_lib.ssm_verify_chunk(p["ssm"], h,
+                                                               c["ssm"], cfg)
+                    x = _residual(x, out, cfg)
+            x, _ = _ffn(p, blk, x, cfg)
             info[f"pos{i}"] = leg
         return x, info
 
     layer_cache = {k: v for k, v in cache.items() if k.startswith("pos")}
     x, info = jax.lax.scan(period_fn, x, (params["layers"], layer_cache))
-    x = rms_norm(params["final_norm"], x, cfg.norm_eps)
-    w_out = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-    logits = unembed(w_out, x, softcap=cfg.final_logit_softcap)
-    return logits, info
+    return _head(params, x, cfg), info
 
 
 def lm_commit_chunk(cache: dict, info: dict, positions: jax.Array,

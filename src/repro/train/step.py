@@ -175,7 +175,7 @@ def make_train_step(model: Model, opt_cfg: AdamWConfig,
                 lambda p: jnp.zeros(p.shape, acc_dt), params)
             m0 = {k: jnp.zeros((), jnp.float32)
                   for k in ("loss", "ce", "acc", "moe_lb_loss",
-                            "moe_z_loss", "moe_dropped")}
+                            "moe_z_loss")}
             (g_sum, m_sum), _ = jax.lax.scan(accum_fn, (g0, m0), micro)
             grads = jax.tree.map(lambda g: g / accum_steps, g_sum)
             metrics = jax.tree.map(lambda m: m / accum_steps, m_sum)

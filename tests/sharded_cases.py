@@ -1,5 +1,5 @@
-"""Multi-device serving cases, run in a subprocess by
-``tests/test_serve_sharded.py``.
+"""Multi-device cases, run in a subprocess by ``tests/test_serve_sharded.py``
+(and the expert-parallel MoE case by ``tests/test_moe.py``).
 
 ``--xla_force_host_platform_device_count`` only takes effect before the
 first jax backend initialization, and ``tests/conftest.py`` imports jax
@@ -185,10 +185,51 @@ def contracts_sharded():
     assert not findings, [f"{f.rule}: {f.message}" for f in findings]
 
 
+def moe_expert_parallel():
+    """The MoE layer under a training mesh -- 8 experts split over a
+    2-way 'model' axis, tokens over a 2-way 'data' axis: each shard runs
+    its own experts inside one shard_map and the shares are summed --
+    gives the one-device layer's output, loss and gradients."""
+    import dataclasses
+
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    from repro.models import moe as M
+
+    cfg = dataclasses.replace(get_config("kimi-k2-1t-a32b").reduced(),
+                              d_model=16, moe_d_ff=32, moe_num_experts=8,
+                              moe_top_k=3)
+    ep = dataclasses.replace(cfg, batch_axes=("data",))
+    key = jax.random.PRNGKey(0)
+    p = M.init_moe(key, cfg, jnp.float32)
+    x = jax.random.normal(jax.random.fold_in(key, 1), (4, 8, cfg.d_model))
+
+    def loss(p, x, c):
+        y, aux = M.apply_moe(p, x, c)
+        return jnp.sum(y * jnp.sin(y)) + aux["moe_lb_loss"], y
+
+    grad = lambda c: jax.jit(jax.value_and_grad(
+        lambda p, x: loss(p, x, c), argnums=(0, 1), has_aux=True))
+    (want_l, want_y), want_g = grad(cfg)(p, x)
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                ("data", "model"))
+    with mesh:
+        assert "shard_map" in str(jax.make_jaxpr(
+            lambda p, x: M.apply_moe(p, x, ep))(p, x))
+        (got_l, got_y), got_g = grad(ep)(p, x)
+    np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y),
+                               atol=1e-5, rtol=0)
+    np.testing.assert_allclose(float(got_l), float(want_l), rtol=1e-5)
+    for g, w in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=1e-4, rtol=1e-4)
+
+
 CASES = {fn.__name__: fn for fn in (
     greedy_attn, greedy_ssm_hybrid, greedy_encdec_vlm,
     logits_and_prefill, spec_matrix, sanitize_sharded,
-    contracts_sharded)}
+    contracts_sharded, moe_expert_parallel)}
 
 
 def main(argv):

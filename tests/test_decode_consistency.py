@@ -13,16 +13,17 @@ from repro.configs import ASSIGNED, get_config
 from repro.models import build_model, make_batch
 from repro.configs.base import ShapeConfig
 
-# One representative per family; MoE archs get a no-drop capacity factor
-# (capacity dropping legitimately differs between grouping layouts).
+# One representative per family (the MoE layer is dropless, so routing
+# does not depend on how tokens are grouped).
 CASES = [
     ("mamba2-2.7b", {}),                       # ssm
     ("qwen2.5-3b", {}),                        # dense GQA + bias
     ("gemma2-2b", {}),                         # local/global + softcaps
     ("gemma-2b", {}),                          # MQA
-    ("jamba-v0.1-52b", {"moe_capacity_factor": 8.0}),   # hybrid + MoE
-    ("kimi-k2-1t-a32b", {"moe_capacity_factor": 8.0}),  # MoE top-8
+    ("jamba-v0.1-52b", {}),                    # hybrid + MoE
+    ("kimi-k2-1t-a32b", {}),                   # MoE top-8
     ("internvl2-2b", {}),                      # VLM early fusion
+    ("granite-4.0-h-small", {}),               # NoPE hybrid, scaled
 ]
 
 

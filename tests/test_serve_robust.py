@@ -32,13 +32,12 @@ from repro.serve import (AdmissionConfig, QueueFull, STATUSES,
                          ServeEngine, bursty_trace, poisson_trace,
                          replay)
 
-# same idiom as test_serve_unified: moe_capacity_factor=8.0 keeps MoE
-# token dropping out of the oracle comparison; "attn" joins the matrix
-# because fault isolation must hold on the plain ring-KV path too
+# "attn" joins the matrix because fault isolation must hold on the plain
+# ring-KV path too
 ARCHS = {
     "attn": ("gptneox-1b", {}),
     "ssm": ("mamba2-2.7b", {}),
-    "hybrid": ("jamba-v0.1-52b", {"moe_capacity_factor": 8.0}),
+    "hybrid": ("jamba-v0.1-52b", {}),
     "enc-dec": ("seamless-m4t-medium", {}),
     "vlm": ("internvl2-2b", {}),
 }

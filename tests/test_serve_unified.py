@@ -21,13 +21,11 @@ from repro.configs import get_config
 from repro.models import build_model
 from repro.serve import ServeEngine
 
-# moe_capacity_factor=8.0 on the MoE archs: capacity never binds, so
-# token dropping can't differ between the full-prompt oracle and the
-# chunk-local prefill groups (the same idiom as test_decode_consistency
-# — with drops, Switch-style routing is legitimately group-dependent).
+# The MoE layer is dropless: routing is per token, so the full-prompt
+# oracle and the chunk-local prefill groups route alike.
 ARCHS = {
     "ssm": ("mamba2-2.7b", {}),
-    "hybrid": ("jamba-v0.1-52b", {"moe_capacity_factor": 8.0}),
+    "hybrid": ("jamba-v0.1-52b", {}),
     "enc-dec": ("seamless-m4t-medium", {}),
     "vlm": ("internvl2-2b", {}),
 }
@@ -150,7 +148,7 @@ def test_chunked_prefill_hybrid_ring_wrap():
     layers carry state — both must match the full-prompt oracle."""
     cfg = dataclasses.replace(
         get_config("jamba-v0.1-52b").reduced(),
-        sliding_window=16, moe_capacity_factor=8.0)
+        sliding_window=16)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(2))
     prompt = [int(1 + (i * 7) % 200) for i in range(24)]   # 24 > window
