@@ -115,7 +115,8 @@ DEFAULT_TRACED_ROOTS: Dict[str, Set[str]] = {
     },
     "models/slotstate.py": {
         "mask_rows", "masked_tree", "decode_advance", "take_layer",
-        "put_layer", "take_row", "put_row", "clear_slot",
+        "put_layer", "take_slot", "put_slot", "take_row", "put_row",
+        "clear_slot",
     },
     "models/ssm.py": {"ssm_prefill_chunk"},
     "serve/quant.py": {"quantize_blockwise", "dequantize_blockwise"},

@@ -446,7 +446,9 @@ def cache_write_decode(cache: dict, k: jax.Array, v: jax.Array,
 def cache_write_chunk(cache: dict, k: jax.Array, v: jax.Array,
                       positions: jax.Array,
                       valid: Optional[jax.Array] = None,
-                      kv_format: Optional[str] = None) -> dict:
+                      kv_format: Optional[str] = None,
+                      layer: Optional[jax.Array] = None,
+                      slot: Optional[jax.Array] = None) -> dict:
     """Bulk-write a prompt *chunk* (b, s, hkv, d) at absolute
     ``positions`` (s,) into the (ring) cache — the chunked-prefill write.
 
@@ -459,18 +461,27 @@ def cache_write_chunk(cache: dict, k: jax.Array, v: jax.Array,
     distinct ring slots, i.e. s <= capacity (the engine clamps its chunk
     size to the smallest layer capacity).  Quantized caches encode on
     the way in — quantize-on-write, inside the jitted chunk step.
+
+    layer, slot: optional traced scalars — ``cache`` is then the
+    period-stacked pool (leaves (n_periods, batch, cap, ...)), the chunk
+    is one row's (b == 1), and it lands at ``[layer, slot, positions %
+    cap]``: s entries written in place, the rest of the pool untouched.
     """
-    cap = cache["slot_pos"].shape[1]
-    b, s = k.shape[0], k.shape[1]
+    cap = cache["slot_pos"].shape[-1]
     slots = (positions % cap).astype(jnp.int32)
-    sp_new = jnp.broadcast_to(positions.astype(jnp.int32), (b, s))
-    vmask = None if valid is None else jnp.broadcast_to(valid, (b, s))
-    sp = cache["slot_pos"].at[:, slots].set(
-        mask_rows(vmask, sp_new, cache["slot_pos"][:, slots]))
+    if layer is None:
+        idx = (slice(None), slots)
+    else:
+        idx = (layer, slot, slots)
+        k, v = k[0], v[0]
+    lead = k.shape[:-2]
+    sp_new = jnp.broadcast_to(positions.astype(jnp.int32), lead)
+    vmask = None if valid is None else jnp.broadcast_to(valid, lead)
+    sp = cache["slot_pos"].at[idx].set(
+        mask_rows(vmask, sp_new, cache["slot_pos"][idx]))
 
     def put(pool, new):
-        return pool.at[:, slots].set(
-            mask_rows(vmask, new, pool[:, slots]))
+        return pool.at[idx].set(mask_rows(vmask, new, pool[idx]))
 
     if is_quantized_cache(cache):
         assert kv_format is not None, "quantized cache needs its kv_format"

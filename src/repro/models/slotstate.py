@@ -15,10 +15,12 @@ special cases:
    scan (``lax.scan`` over the period axis) the slot axis is 0, and
    :func:`take_row` / :func:`put_row` move one slot's row in and out
    with ``slot`` traced, so one executable serves every slot.  The
-   decode step instead carries the whole period-stacked pool through
-   its scan and addresses one layer with :func:`take_layer` /
-   :func:`put_layer` (``layer`` traced), so the pool is updated in
-   place rather than restacked every step.
+   decode step and the prefill chunk instead carry the whole
+   period-stacked pool through their scans and address one layer with
+   :func:`take_layer` / :func:`put_layer`, or one layer's slot row with
+   :func:`take_slot` / :func:`put_slot` (``layer`` and ``slot``
+   traced), so the pool is updated in place rather than restacked at
+   every step or chunk.
 
 2. **Eviction** (:func:`clear_slot`) is uniform: parts with ring
    bookkeeping (a ``slot_pos`` leaf — self-attn KV *and* cross-attn KV)
@@ -120,6 +122,24 @@ def put_layer(pool: Any, part: Any, layer: jax.Array) -> Any:
         lambda p, r: jax.lax.dynamic_update_index_in_dim(
             p, r.astype(p.dtype), layer, 0),
         pool, part)
+
+
+def take_slot(tree: Any, layer: jax.Array, slot: jax.Array) -> Any:
+    """Read one layer's row ``slot`` (kept as a size-1 axis) out of the
+    period-stacked pool; the compiler fuses the slice into its
+    consumer."""
+    return take_row(take_layer(tree, layer), slot)
+
+
+def put_slot(pool: Any, row: Any, layer: jax.Array,
+             slot: jax.Array) -> Any:
+    """Inverse of :func:`take_slot`: write the size-1 row back at
+    ``[layer, slot]`` in place."""
+    return jax.tree.map(
+        lambda p, r: jax.lax.dynamic_update_slice(
+            p, r[None].astype(p.dtype),
+            (layer, slot) + (jnp.int32(0),) * (p.ndim - 2)),
+        pool, row)
 
 
 def take_row(tree: Any, slot: jax.Array) -> Any:

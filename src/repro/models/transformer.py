@@ -669,6 +669,14 @@ def lm_prefill_chunk(params: dict, cache: dict, tokens: jax.Array,
       * cross-attention reads the slot's cross-KV written once by
         :func:`lm_encode_slot` (read-only here, like decode).
 
+    The period-stacked pool is carried through the layer scan, as in
+    :func:`lm_decode_step`: period ``l`` reads the slot's row at
+    ``pool[l, slot]`` and writes back only what the chunk changed — its
+    ``s`` ring entries at ``[l, slot, positions % cap]``, the recurrent
+    state's row at ``[l, slot]``.  Under the engine's jit, which donates
+    the pool, a chunk therefore updates the pool in place: no layer or
+    pool is copied.
+
     Returns (logits (1, vocab) at the last valid position, updated
     cache).
     """
@@ -681,14 +689,15 @@ def lm_prefill_chunk(params: dict, cache: dict, tokens: jax.Array,
     positions = pos_offset + jnp.arange(s, dtype=jnp.int32)   # (s,)
     valid = jnp.arange(s) < valid_len
 
-    def period_fn(x, scanned):
-        period_params, period_cache = scanned
-        new_cache = {}
+    def period_fn(carry, scanned):
+        x, pool = carry
+        period_params, l = scanned
+        new_pool = {}
         for i, blk in enumerate(pattern):
             p = period_params[f"pos{i}"]
-            c = period_cache[f"pos{i}"]
+            c = pool[f"pos{i}"]
             kv_fmt = cfg.kv_format_for(i)
-            entry = {}
+            entry = dict(c)
             with jax.named_scope(blk.mixer):
                 if blk.mixer == "attn":
                     h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
@@ -696,7 +705,12 @@ def lm_prefill_chunk(params: dict, cache: dict, tokens: jax.Array,
                     k, v = attn.project_kv(p["attn"], h)
                     q = _rope(q, positions[None, :], cfg)
                     k = _rope(k, positions[None, :], cfg)
-                    kv_row = slotstate.take_row(c["kv"], slot)
+                    kv_row = slotstate.take_slot(c["kv"], l, slot)
+                    if attn.is_quantized_cache(kv_row):
+                        # packed codes unpack in a layout of their own:
+                        # sliced out first, the row is relaid, where a
+                        # slice fused into the unpack relays the pool
+                        kv_row = jax.lax.optimization_barrier(kv_row)
                     # Attend against the PRE-write history concatenated with
                     # the chunk's own raw K/V.  Writing first and attending
                     # over the ring would be wrong once a chunk wraps a
@@ -719,13 +733,13 @@ def lm_prefill_chunk(params: dict, cache: dict, tokens: jax.Array,
                         positions[None, :], window=blk.window,
                         softcap=cfg.attn_logit_softcap, scale=cfg.attn_scale)
                     x = _residual(x, attn.project_out(p["attn"], o), cfg)
-                    kv_row = attn.cache_write_chunk(kv_row, k, v, positions,
-                                                    valid, kv_format=kv_fmt)
-                    entry["kv"] = slotstate.put_row(c["kv"], kv_row, slot)
+                    entry["kv"] = attn.cache_write_chunk(
+                        c["kv"], k, v, positions, valid, kv_format=kv_fmt,
+                        layer=l, slot=slot)
                     if blk.cross_attn and "cross_kv" in c:
                         h = rms_norm(p["ln_cross"], x, cfg.norm_eps)
                         q = attn.project_q(p["cross"], h)
-                        ckv_row = slotstate.take_row(c["cross_kv"], slot)
+                        ckv_row = slotstate.take_slot(c["cross_kv"], l, slot)
                         ck, cv = attn.cache_kv(ckv_row, kv_fmt, cfg.head_dim,
                                                out_dtype=x.dtype)
                         o = attn.cache_attention(
@@ -733,21 +747,26 @@ def lm_prefill_chunk(params: dict, cache: dict, tokens: jax.Array,
                             jnp.full_like(positions, jnp.int32(2 ** 30))[
                                 None, :], scale=cfg.attn_scale)
                         x = _residual(x, attn.project_out(p["cross"], o), cfg)
-                        entry["cross_kv"] = c["cross_kv"]    # read-only
                 elif blk.mixer == "ssm":
                     h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
-                    ssm_row = slotstate.take_row(c["ssm"], slot)
+                    # the row is sliced out once, whole: with the slice
+                    # fused into its two readers, the compiler staged a
+                    # small stack (granite's) through VMEM and back
+                    ssm_row = jax.lax.optimization_barrier(
+                        slotstate.take_slot(c["ssm"], l, slot))
                     out, ssm_row = ssm_lib.ssm_prefill_chunk(
                         p["ssm"], h, ssm_row, cfg, valid, valid_len)
                     x = _residual(x, out, cfg)
-                    entry["ssm"] = slotstate.put_row(c["ssm"], ssm_row, slot)
+                    entry["ssm"] = slotstate.put_slot(c["ssm"], ssm_row, l,
+                                                      slot)
             x, _ = _ffn(p, blk, x, cfg)
-            new_cache[f"pos{i}"] = entry
-        return x, new_cache
+            new_pool[f"pos{i}"] = entry
+        return (x, new_pool), None
 
     layer_cache = {k: v for k, v in cache.items() if k.startswith("pos")}
-    x, new_layer_cache = jax.lax.scan(
-        period_fn, x, (params["layers"], layer_cache))
+    (x, new_layer_cache), _ = jax.lax.scan(
+        period_fn, (x, layer_cache),
+        (params["layers"], jnp.arange(cfg.n_periods, dtype=jnp.int32)))
     x_last = jax.lax.dynamic_slice_in_dim(x, valid_len - 1, 1, axis=1)
     logits = _head(params, x_last, cfg)[:, 0]
     out_cache = dict(new_layer_cache)

@@ -300,29 +300,39 @@ class ServeEngine:
             self._sample_key = jax.device_put(self._sample_key,
                                               self._sh["replicated"])
 
-        # jitted executables (shared across reset(); decode loops are
-        # cached per fused length K).  One executable per admission step
-        # kind — token chunks, embed chunks (VLM), encode (enc-dec) —
-        # each compiled exactly once (the sanitizer asserts this).
+        self._make_executables()
+
+    def _make_executables(self) -> None:
+        """Jit the engine's executables (shared across reset(); decode
+        loops are cached per fused length K).  One executable per
+        admission step kind — token chunks, embed chunks (VLM), encode
+        (enc-dec) — each compiled exactly once (the sanitizer asserts
+        this).  The prefill chunks and ``clear_slot`` donate the pool
+        they are given, as the fused loop does: each updates it in place
+        and returns it, and the caller must drop the one it passed."""
+        model, mesh = self.model, self.mesh
         repl = self._sh["replicated"] if mesh is not None else None
         cache_sh = self._sh["cache"] if mesh is not None else None
         state_sh = self._sh["state"] if mesh is not None else None
         self._loops: Dict[int, jax.stages.Wrapped] = {}
-        self._prefill_chunk_fn = self._jit(model.prefill_chunk,
-                                           (repl, cache_sh))
+        self._prefill_chunk_fn = self._jit(
+            model.prefill_chunk, (repl, cache_sh), donate_argnums=(1,))
         if model.cfg.frontend == "vision":
             self._prefill_embeds_fn = self._jit(
                 lambda p, c, emb, slot, off, vl: model.prefill_chunk(
                     p, c, jnp.zeros((emb.shape[1],), jnp.int32), slot,
                     off, vl, embeds=emb),
-                (repl, cache_sh))
+                (repl, cache_sh), donate_argnums=(1,))
         if model.cfg.is_encoder_decoder:
             self._encode_slot_fn = self._jit(model.encode_slot, cache_sh)
-        self._clear_slot_fn = self._jit(model.clear_slot, cache_sh)
+        self._clear_slot_fn = self._jit(model.clear_slot, cache_sh,
+                                        donate_argnums=(0,))
         if self._draft_cache is not None:
             dm = self.spec.draft_model
-            self._draft_prefill_fn = self._jit(dm.prefill_chunk)
-            self._draft_clear_fn = self._jit(dm.clear_slot)
+            self._draft_prefill_fn = self._jit(dm.prefill_chunk,
+                                               donate_argnums=(1,))
+            self._draft_clear_fn = self._jit(dm.clear_slot,
+                                             donate_argnums=(0,))
         self._admit_fn = self._jit(self._admit_update, (repl, state_sh))
         # cancel / fault-arm share _admit_update's shape: one jitted
         # slot-state write each, compiled at most once, dispatched only
@@ -332,12 +342,14 @@ class ServeEngine:
         self._fault_cache_fns: Dict[tuple, jax.stages.Wrapped] = {}
         self._cache_sh = cache_sh
 
-    def _jit(self, fn, out_shardings=None):
+    def _jit(self, fn, out_shardings=None, donate_argnums=()):
         """jax.jit, pinning outputs to their serving shardings when the
-        engine is mesh-native (mesh=None compiles exactly as before)."""
+        engine is mesh-native (mesh=None compiles exactly as before);
+        ``donate_argnums`` is passed on either way."""
         if self.mesh is None:
-            return jax.jit(fn)
-        return jax.jit(fn, out_shardings=out_shardings)
+            return jax.jit(fn, donate_argnums=donate_argnums)
+        return jax.jit(fn, out_shardings=out_shardings,
+                       donate_argnums=donate_argnums)
 
     def _host_read(self, x) -> np.ndarray:
         """The engine's ONE designed device→host sync point per dispatch.

@@ -3,12 +3,20 @@ vs N per-step dispatches (greedy AND sampled), chunked pooled prefill vs
 the width-1 prefill oracle, mid-loop slot finishes, ring-wrap
 boundaries, and run() truncation flushing."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.models import attention as attn
 from repro.models import build_model
+from repro.models import slotstate
+from repro.models import ssm as ssm_lib
+from repro.models import transformer as T
+from repro.models.layers import rms_norm
 from repro.serve import ServeEngine
 
 
@@ -222,3 +230,117 @@ def test_decode_loop_donates_pool(arch):
         outs.append(_tokens(eng.run()))
     assert outs[0] == outs[1]
     assert [len(t) for t in outs[0]] == [12, 5]
+
+
+def _scanned_prefill_chunk(cfg, params, cache, tokens, slot, pos_offset,
+                           valid_len):
+    """The prefill chunk with the pool as the layer scan's input: each
+    layer's row is sliced out with ``take_row`` and the layer restacked
+    whole with ``put_row``.  The oracle for the in-place chunk, for
+    decoder-only attention and SSM layers."""
+    s = tokens.shape[0]
+    x = T._embed(params, tokens[None, :], cfg)
+    positions = pos_offset + jnp.arange(s, dtype=jnp.int32)
+    valid = jnp.arange(s) < valid_len
+
+    def period_fn(x, scanned):
+        period_params, period_cache = scanned
+        new_cache = {}
+        for i, blk in enumerate(cfg.block_pattern()):
+            p, c = period_params[f"pos{i}"], period_cache[f"pos{i}"]
+            h = rms_norm(p["ln_mix"], x, cfg.norm_eps)
+            if blk.mixer == "attn":
+                q = T._rope(attn.project_q(p["attn"], h), positions[None],
+                            cfg)
+                k, v = attn.project_kv(p["attn"], h)
+                k = T._rope(k, positions[None], cfg)
+                row = slotstate.take_row(c["kv"], slot)
+                o = attn.cache_attention(
+                    q, jnp.concatenate([row["k"], k], axis=1),
+                    jnp.concatenate([row["v"], v], axis=1),
+                    jnp.concatenate([row["slot_pos"], jnp.where(
+                        valid, positions, -1)[None]], axis=1),
+                    positions[None], window=blk.window,
+                    softcap=cfg.attn_logit_softcap, scale=cfg.attn_scale)
+                x = T._residual(x, attn.project_out(p["attn"], o), cfg)
+                row = attn.cache_write_chunk(row, k, v, positions, valid)
+                new_cache[f"pos{i}"] = {
+                    "kv": slotstate.put_row(c["kv"], row, slot)}
+            else:
+                out, row = ssm_lib.ssm_prefill_chunk(
+                    p["ssm"], h, slotstate.take_row(c["ssm"], slot), cfg,
+                    valid, valid_len)
+                x = T._residual(x, out, cfg)
+                new_cache[f"pos{i}"] = {
+                    "ssm": slotstate.put_row(c["ssm"], row, slot)}
+            x, _ = T._ffn(p, blk, x, cfg)
+        return x, new_cache
+
+    x, cache = jax.lax.scan(period_fn, x, (params["layers"], cache))
+    x_last = jax.lax.dynamic_slice_in_dim(x, valid_len - 1, 1, axis=1)
+    return T._head(params, x_last, cfg)[:, 0], cache
+
+
+def _filled(cache, key):
+    """Every leaf of ``cache`` drawn at random: payload normal, ring
+    positions in [-1, 64)."""
+    leaves, tree = jax.tree.flatten(cache)
+    keys = jax.random.split(key, len(leaves))
+    return tree.unflatten([
+        jax.random.randint(k, a.shape, -1, 64, a.dtype)
+        if jnp.issubdtype(a.dtype, jnp.integer)
+        else jax.random.normal(k, a.shape, a.dtype)
+        for a, k in zip(leaves, keys)])
+
+
+def _snapshot(tree):
+    """A host copy of ``tree`` that leaves its arrays donatable: on the
+    CPU a host view of an array pins its buffer, and a pinned buffer is
+    copied rather than donated."""
+    return jax.device_get(jax.tree.map(jnp.copy, tree))
+
+
+def _assert_rows(got, want, slot, row=None):
+    """Every pool row but ``slot`` (axis 1) of ``got`` equals ``want``
+    bit for bit; row ``slot`` equals ``row`` when it is given."""
+    for g, w, r in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                       jax.tree.leaves(row if row is not None else want)):
+        np.testing.assert_array_equal(np.delete(g, slot, axis=1),
+                                      np.delete(w, slot, axis=1))
+        if row is not None:
+            np.testing.assert_array_equal(g[:, slot], r[:, slot])
+
+
+@pytest.mark.parametrize("arch", ["gptneox-1b", "mamba2-2.7b",
+                                  "granite-4.0-h-small"])
+def test_admission_updates_pool_in_place(arch):
+    """``clear_slot`` and a three-chunk prefill (the last chunk part
+    padding) into slot 1 of a pool whose every row holds data, through
+    the engine's executables, which consume the pool they are given:
+    every other row stays bit-identical, and the logits and row 1 are
+    those the scanned form gives from a snapshot of the pool taken
+    before the donated calls."""
+    cfg = get_config(arch).reduced()
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(3))
+    eng = ServeEngine(model, params, batch=3, max_seq=64, prefill_chunk=8)
+    slot = jnp.int32(1)
+    cache = _filled(eng.cache, jax.random.PRNGKey(4))
+    full = _snapshot(cache)
+    donated = jax.tree.leaves(cache)
+    cache = eng._clear_slot_fn(cache, slot)
+    assert all(a.is_deleted() for a in donated)
+    want_cache = _snapshot(cache)
+    _assert_rows(want_cache, full, 1)
+    scanned = jax.jit(functools.partial(_scanned_prefill_chunk, cfg))
+    prompt = np.random.default_rng(5).integers(0, cfg.vocab_size, 21)
+    for off in range(0, len(prompt), 8):
+        part = prompt[off:off + 8]
+        chunk = jnp.asarray(np.pad(part, (0, 8 - len(part))), jnp.int32)
+        args = (chunk, slot, jnp.int32(off), jnp.int32(len(part)))
+        donated = jax.tree.leaves(cache)
+        logits, cache = eng._prefill_chunk_fn(params, cache, *args)
+        assert all(a.is_deleted() for a in donated)
+        want, want_cache = scanned(params, want_cache, *args)
+        np.testing.assert_array_equal(np.asarray(logits), np.asarray(want))
+    _assert_rows(_snapshot(cache), full, 1, row=want_cache)

@@ -104,13 +104,15 @@ def test_prefill_chunk_compiles_at_full_width(one_chip):
     assert _fits(compiled) > 2e9
 
 
-def _loop_engine(model):
-    """The engine's own methods on an engine that holds no arrays."""
+def _array_less_engine(model):
+    """An engine that holds no arrays, with its own executables."""
     from repro.serve import ServeEngine
     engine = object.__new__(ServeEngine)
     engine.model, engine.batch, engine.max_seq = model, BATCH, MAX_SEQ
     engine._temperature, engine._top_k = 0.0, 0
     engine.spec, engine.mesh, engine._sh = None, None, None
+    engine._draft_cache = None
+    engine._make_executables()
     return engine
 
 
@@ -121,26 +123,41 @@ def _body_copied_shapes(hlo: str) -> set:
     return set(re.findall(r"= \w+\[([\d,]*)\]\S* copy\(", body))
 
 
+def _executable(engine, name, params, cache):
+    """(jitted executable, its arguments) for the engine's ``name``."""
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    if name == "loop":
+        state = jax.eval_shape(engine._init_state)
+        key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+        return engine._make_decode_loop(2), (params, cache, state, key)
+    if name == "prefill_chunk":
+        tokens = jax.ShapeDtypeStruct((CHUNK,), jnp.int32)
+        return engine._prefill_chunk_fn, (params, cache, tokens, i32, i32,
+                                          i32)
+    assert name == "clear_slot"
+    return engine._clear_slot_fn, (cache, i32)
+
+
 @pytest.mark.parametrize("arch,kv_format", [
     ("gptneox-1b", None), ("gptneox-1b", "float4_e2m1fn"),
     ("mamba2-2.7b", None), ("granite-4.0-h-small", None)])
-def test_decode_loop_updates_pool_in_place(one_chip, arch, kv_format):
-    """The fused loop donates the slot pool and carries it through the
-    layer scan: its output aliases the whole pool and no step copies
-    anything pool-shaped.  A dense pool (bf16 KV, SSM state, or both in
-    granite's mixed pool beside its grouped-matmul MoE) is then never
-    held twice: the temporaries stay far below one pool.  The
+@pytest.mark.parametrize("name", ["loop", "prefill_chunk", "clear_slot"])
+def test_executable_updates_pool_in_place(one_chip, name, arch, kv_format):
+    """The fused loop, the prefill chunk and ``clear_slot`` each donate
+    the slot pool and update it in place: the output aliases the whole
+    pool, and no loop body (the decode steps, the chunk's layer scan)
+    copies anything pool-shaped.  A dense pool (bf16 KV, SSM state, or
+    both in granite's mixed pool beside its grouped-matmul MoE) is then
+    never held twice: the temporaries stay far below one pool.  The
     packed fp4 pool is exempt from that bound: the loop keeps it in a
     layout of its own, into which the entry converts it once per call,
     and its XLA path dequantizes a layer's K/V per step."""
     model = _model(kv_format, arch)
-    engine = _loop_engine(model)
+    engine = _array_less_engine(model)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     cache = jax.eval_shape(lambda: model.init_cache(BATCH, MAX_SEQ))
-    state = jax.eval_shape(engine._init_state)
-    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-    compiled = engine._make_decode_loop(2).lower(
-        *_on(one_chip, (params, cache, state, key))).compile()
+    fn, args = _executable(engine, name, params, cache)
+    compiled = fn.lower(*_on(one_chip, args)).compile()
     pool = jax.tree.leaves(cache)
     pool_bytes = sum(a.size * a.dtype.itemsize for a in pool)
     ma = compiled.memory_analysis()
